@@ -220,10 +220,38 @@ def profile_engine(engine: str, reps: int = 3) -> List[Dict]:
     return rows
 
 
-def bench_fleet(n_points: int = FLEET_POINTS, reps: int = 1) -> Dict:
+def fleet_points(n_points: int = FLEET_POINTS) -> List:
+    """The unit-latency timing sweep both fleet sides evaluate."""
+    from repro.explore import timing_space
+
+    sp = timing_space(scalar_alu=(1, 2, 3, 4), router=(1, 2, 3, 4))
+    return list(sp.points())[:n_points]
+
+
+def bench_pool_baseline(pts: List) -> Dict:
+    """The pool-parallel per-point pipeline over ``pts`` (numpy engine).
+
+    It forks workers, so it runs before anything in this process
+    touches JAX: a child forked from a process that holds the device
+    runtime inherits a chip it cannot use.
+    """
+    from repro.core.mapping import CostParams
+    from repro.explore import ExplorationEngine
+
+    pool = os.cpu_count() or 1
+    base = ExplorationEngine("tiny_cnn", params=CostParams(batch=BATCH),
+                             pool=pool, engine="auto")
+    t0 = time.perf_counter()
+    brecs = base.evaluate(pts, fidelity="simulate")
+    return {"records": brecs, "wall_s": time.perf_counter() - t0,
+            "pool": pool}
+
+
+def bench_fleet(pts: List, baseline: Dict, reps: int = 1) -> Dict:
     """256-point unit-latency sweep at simulate fidelity: the vmapped
     fleet evaluator (one compile, batched XLA decode) vs the
-    pool-parallel per-point pipeline.
+    pool-parallel per-point pipeline measured by
+    :func:`bench_pool_baseline`.
 
     The baseline compiles each timing point's own chip, so its results
     can legitimately diverge from the fleet's pinned-program semantics
@@ -232,14 +260,10 @@ def bench_fleet(n_points: int = FLEET_POINTS, reps: int = 1) -> Dict:
     canonical chip IS its own chip.
     """
     from repro.core.mapping import CostParams
-    from repro.explore import ExplorationEngine, timing_space
+    from repro.explore import ExplorationEngine
 
-    sp = timing_space(scalar_alu=(1, 2, 3, 4), router=(1, 2, 3, 4))
-    pts = list(sp.points())[:n_points]
-    params = CostParams(batch=BATCH)
-    pool = os.cpu_count() or 1
-
-    jx = ExplorationEngine("tiny_cnn", params=params, engine="jax")
+    jx = ExplorationEngine("tiny_cnn", params=CostParams(batch=BATCH),
+                           engine="jax")
     wall_fleet = float("inf")
     for _ in range(reps):
         t0 = time.perf_counter()
@@ -247,12 +271,8 @@ def bench_fleet(n_points: int = FLEET_POINTS, reps: int = 1) -> Dict:
         wall_fleet = min(wall_fleet, time.perf_counter() - t0)
     assert all(r.ok for r in jrecs), [r.error for r in jrecs if not r.ok]
 
-    base = ExplorationEngine("tiny_cnn", params=params, pool=pool,
-                             engine="auto")
-    t0 = time.perf_counter()
-    brecs = base.evaluate(pts, fidelity="simulate")
-    wall_pool = time.perf_counter() - t0
-
+    brecs = baseline["records"]
+    wall_pool = baseline["wall_s"]
     defaults = next(i for i, p in enumerate(pts)
                     if (p.scalar_alu_latency, p.vector_alu_latency,
                         p.weight_load_rows_per_cycle,
@@ -264,7 +284,7 @@ def bench_fleet(n_points: int = FLEET_POINTS, reps: int = 1) -> Dict:
             f"{brecs[defaults].cycles}")
     return {
         "workload": "tiny_cnn", "batch": BATCH, "points": len(pts),
-        "pool": pool,
+        "pool": baseline["pool"],
         "wall_s": {"fleet_jax": round(wall_fleet, 3),
                    "pool_baseline": round(wall_pool, 3)},
         "speedup": round(wall_pool / wall_fleet, 2),
@@ -453,8 +473,10 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", RuntimeWarning)
-        doc = to_doc(bench_rows(reps=reps),
-                     fleet=bench_fleet(reps=1),
+        pts = fleet_points()
+        baseline = bench_pool_baseline(pts)     # forks: before any JAX
+        rows = bench_rows(reps=reps)
+        doc = to_doc(rows, fleet=bench_fleet(pts, baseline, reps=1),
                      func_pallas=bench_func_pallas())
     print(report(doc))
     if args.json:

@@ -18,8 +18,8 @@ pip install hypothesis \
 set -e
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
-echo "== tier-1 tests =="
-python -m pytest -x -q
+echo "== tier-1 tests (CPU; Pallas in interpret mode) =="
+JAX_PLATFORMS=cpu python -m pytest -x -q
 
 echo "== benchmark smoke (analytic, no roofline) =="
 python -m benchmarks.run --quick --skip-roofline > /dev/null

@@ -23,17 +23,21 @@ shape** and, crucially, makes the machine's timing constants a
   under ``vmap(in_axes=(0, None))`` XLA computes it once and batches
   only the latency arithmetic.
 
-Bit-identity strategy: the device returns only *per-instruction* int64
-values and float64 latencies computed with formulas mirrored
-term-for-term from :class:`MachineModel`'s ``*_cycles_array`` methods
-(int64 arithmetic, one final ``astype(float64)``, IEEE division) — every
-*sum* (event ledgers, unit-busy, run prefix sums) happens on the host in
-the shared numpy back half.  Inputs are padded to power-of-two buckets
-to bound jit recompiles; all scans are prefix-safe, so padding appended
+Bit-identity strategy: the device computes and returns only
+*per-instruction* int64 values — resolved operands and the integer
+vector / MVM latencies, mirrored term-for-term from
+:class:`MachineModel`'s ``*_cycles_array`` methods.  No float64 touches
+the device: the TPU emulates it with less than IEEE precision (its
+divisions, int64 conversions and even an upload/download round trip of
+a float64 constant differ from numpy), so the float latencies
+(constants, ``rows / rate``, ``bytes / link width``) and every *sum*
+(event ledgers, unit-busy, run prefix sums) happen on the host in the
+shared numpy back half.  Inputs are padded to power-of-two buckets to
+bound jit recompiles; all scans are prefix-safe, so padding appended
 after the real rows never perturbs them and outputs are sliced back to
 the true length.
 
-Int semantics: everything runs under ``jax.experimental.enable_x64`` so
+Int semantics: everything runs under ``jax.enable_x64(True)`` so
 register arithmetic wraps in int64 exactly like the numpy engine.
 Programs with control flow / scalar-ALU chains take the same
 decode-time unroll path as the numpy engine; anything undecodable falls
@@ -50,7 +54,7 @@ from .isa import Isa, Program
 from .machine import MachineModel
 from .vectorsim import (
     StageDecoder, DecodeUnsupported, _DecodedStage, _Prep, _finish_decode,
-    replay_stage, _END, _K_VEC, _K_MVM, _K_WLOAD, _K_BCAST, _K_CONST,
+    replay_stage, _END, _K_VEC, _K_MVM, _K_WLOAD, _K_BCAST,
     _K_SEND, _K_RECV, _K_GLD, _K_GST, _K_SYNC, _K_HALT,
     _S_VLEN, _S_VREP, _S_CHANNEL, _S_MASK_LO, _S_MASK_HI,
     _S_SEG_IN, _S_SEG_OUT, _S_NLEN, _I8_FLAG,
@@ -63,8 +67,6 @@ __all__ = ["JaxStageDecoder", "FleetStageDecoder", "MachineTables",
 _INT_KEYS = ("vector_lanes", "vector_alu_latency", "vector_mul_latency",
              "vector_special_latency", "mvm_interval_beats",
              "mvm_fill_beats")
-_FLT_KEYS = ("scalar_alu_cycles", "scalar_ldst_cycles",
-             "weight_load_rows_per_cycle", "link_bytes_per_cycle")
 
 # instruction columns shipped to the device (plus op / starts)
 _COL_NAMES = ("dst", "a", "imm", "sreg", "src", "len", "rows", "mg",
@@ -82,8 +84,8 @@ _VLEN_COL = 0
 class MachineTables:
     """Stacked timing constants — the ``vmap`` axis of a fleet.
 
-    ``arrays`` is a tuple of ``(n_machines,)`` columns in
-    ``_INT_KEYS + _FLT_KEYS`` order (int64 then float64), built from
+    ``arrays`` is a tuple of ``(n_machines,)`` int64 columns in
+    ``_INT_KEYS`` order, built from
     :meth:`MachineModel.timing_constants` so the batched latency
     arithmetic stays bit-identical to each machine's own accessors.
     """
@@ -97,35 +99,13 @@ class MachineTables:
     @classmethod
     def stack(cls, machines: List[MachineModel]) -> "MachineTables":
         rows = [m.timing_constants() for m in machines]
-        arrays = tuple(
-            np.array([r[k] for r in rows], dtype=np.int64)
-            for k in _INT_KEYS
-        ) + tuple(
-            np.array([r[k] for r in rows], dtype=np.float64)
-            for k in _FLT_KEYS
-        )
-        return cls(arrays)
+        return cls(tuple(np.array([r[k] for r in rows], dtype=np.int64)
+                         for k in _INT_KEYS))
 
 
-def _scalar_row(tc: Dict[str, float]) -> Tuple[np.ndarray, ...]:
+def _scalar_row(tc: Dict[str, int]) -> Tuple[np.ndarray, ...]:
     """One machine's constants as 0-d arrays (the unbatched call)."""
-    return tuple(np.int64(tc[k]) for k in _INT_KEYS) + \
-        tuple(np.float64(tc[k]) for k in _FLT_KEYS)
-
-
-def _latsel_table(dec: StageDecoder) -> np.ndarray:
-    """Per-op constant-latency selector: 0 = none (boundary / batched
-    kinds), 1 = literal 1.0, 2 = scalar-ALU, 3 = scalar-load/store —
-    mirrors the ``const`` table in :class:`StageDecoder.__init__`."""
-    t = np.zeros(dec.isa.n_ops, dtype=np.int32)
-    t[dec.kind == _K_CONST] = 1
-    for i in (dec.id_addi, dec.id_lui):
-        if i >= 0:
-            t[i] = 2
-    for i in (dec.id_sld, dec.id_sst):
-        if i >= 0:
-            t[i] = 3
-    return t
+    return tuple(np.int64(tc[k]) for k in _INT_KEYS)
 
 
 def _bucket(n: int, lo: int = 8) -> int:
@@ -143,8 +123,7 @@ _EXEC_CACHE: Dict[tuple, Tuple[Any, Any]] = {}
 
 
 def _build_exec(kind_t: np.ndarray, vcls_t: np.ndarray,
-                latsel_t: np.ndarray, ids: Tuple[int, ...],
-                n_regs: int) -> Tuple[Any, Any]:
+                ids: Tuple[int, ...], n_regs: int) -> Tuple[Any, Any]:
     """Compile the stage pass for one ISA-table fingerprint.
 
     Returns ``(single, fleet)`` where ``single(sc, cols)`` evaluates one
@@ -156,22 +135,21 @@ def _build_exec(kind_t: np.ndarray, vcls_t: np.ndarray,
     import jax.numpy as jnp
     from jax import lax
 
+    from ..compile_cache import enable_compile_cache
+    enable_compile_cache()
     id_lui, id_addi, id_cfg, id_cfgr, id_setvl = ids
     kind_c = jnp.asarray(kind_t.astype(np.int32))
     vcls_c = jnp.asarray(vcls_t.astype(np.int32))
-    latsel_c = jnp.asarray(latsel_t)
     sreg_ids = jnp.asarray(_SREG_IDS)
 
     def stage_pass(sc, cols):
-        (lanes, v_alu, v_mul, v_special, ivl, fill,
-         alu_f, ldst_f, wl_rate, link_bpc) = sc
+        lanes, v_alu, v_mul, v_special, ivl, fill = sc
         op = cols["op"]
         starts = cols["starts"]
         n = op.shape[0]
         idx = jnp.arange(n, dtype=jnp.int64)
         kind = kind_c[op]
         vcls = vcls_c[op]
-        latsel = latsel_c[op]
 
         def excl_cummax(x):
             inc = lax.cummax(x, axis=0)
@@ -227,45 +205,33 @@ def _build_exec(kind_t: np.ndarray, vcls_t: np.ndarray,
             jnp.take_along_axis(jnp.broadcast_to(sval[:, None], sw.shape),
                                 jnp.maximum(slast, 0), axis=0), 0)
 
-        # ---- MG occupancy: segmented cumulative OR -------------------
+        # ---- MG occupancy: dense (n, 32) last-load timeline ----------
+        # bit g of ``loaded`` is set when the reader's program loaded MG
+        # g strictly before it (MG ids are 5-bit register fields) — the
+        # segmented cumulative OR of the numpy engine, as one cummax: a
+        # ``lax.associative_scan`` here took minutes of TPU compile at
+        # 10^5-row buckets, a cummax seconds
         is_wl = kind == _K_WLOAD
-        bits = jnp.where(is_wl, jnp.asarray(1, jnp.int64) << cols["mg"], 0)
-        segfirst = idx == starts
+        mgs = jnp.arange(32, dtype=jnp.int64)
+        mw = is_wl[:, None] & (cols["mg"][:, None] == mgs[None, :])
+        mlast = excl_cummax(jnp.where(mw, idx[:, None], -1))
+        loaded = jnp.sum(jnp.where(mlast >= starts[:, None],
+                                   jnp.asarray(1, jnp.int64) << mgs, 0),
+                         axis=1)
 
-        def _comb(xa, xb):
-            v1, f1 = xa
-            v2, f2 = xb
-            return jnp.where(f2, v2, v1 | v2), f1 | f2
-
-        occ_incl, _ = lax.associative_scan(_comb, (bits, segfirst))
-        lwl = excl_cummax(jnp.where(is_wl, idx, -1))
-        loaded = jnp.where(lwl >= starts,
-                           occ_incl[jnp.maximum(lwl, 0)], 0)
-
-        # ---- latencies (term-for-term MachineModel mirrors) ----------
-        zero = jnp.asarray(0.0, jnp.float64)
-        one = jnp.asarray(1.0, jnp.float64)
-        consts = jnp.stack([zero, one,
-                            jnp.asarray(alu_f, jnp.float64),
-                            jnp.asarray(ldst_f, jnp.float64)])
-        lat = consts[latsel]
-
+        # ---- integer latencies (term-for-term MachineModel mirrors) --
+        # vector and MVM latencies are exact int64 here; the float ones
+        # (constants, rows / rate, bytes / link width) are priced on the
+        # host, where division rounds as IEEE float64 on every backend
         n_el = (jnp.maximum(scur[:, 0], 1)       # vlen
                 * jnp.maximum(scur[:, 1], 1))    # vrep
         n_el = jnp.maximum(n_el, 1)
         beats = -(-n_el // lanes)                # ceil-div, exact int64
         vlat = jnp.where(vcls == 2, beats * v_special,
-                         beats + jnp.where(vcls == 1, v_mul, v_alu)
-                         ).astype(jnp.float64)
-        lat = jnp.where(kind == _K_VEC, vlat, lat)
-        lat = jnp.where(kind == _K_WLOAD,
-                        cols["rows"].astype(jnp.float64) / wl_rate, lat)
-        lat = jnp.where(kind == _K_MVM,
-                        (cols["rep"] * ivl + fill).astype(jnp.float64),
-                        lat)
-        lat = jnp.where(kind == _K_BCAST,
-                        jnp.maximum(one, rd_size.astype(jnp.float64)
-                                    / link_bpc), lat)
+                         beats + jnp.where(vcls == 1, v_mul, v_alu))
+        lat = jnp.where(kind == _K_VEC, vlat,
+                        jnp.where(kind == _K_MVM,
+                                  cols["rep"] * ivl + fill, 0))
 
         resolved = {"core": rd_core, "size": rd_size, "loaded": loaded}
         for k, key in enumerate(_SREG_KEYS):
@@ -279,14 +245,13 @@ def _build_exec(kind_t: np.ndarray, vcls_t: np.ndarray,
 
 
 def _exec_for(dec: StageDecoder, n_regs: int) -> Tuple[Any, Any]:
-    latsel = _latsel_table(dec)
-    key = (dec.kind.tobytes(), dec.vcls.tobytes(), latsel.tobytes(),
+    key = (dec.kind.tobytes(), dec.vcls.tobytes(),
            dec.id_lui, dec.id_addi, dec.id_cfg, dec.id_cfgr,
            dec.id_setvl, n_regs)
     got = _EXEC_CACHE.get(key)
     if got is None:
         got = _EXEC_CACHE[key] = _build_exec(
-            dec.kind, dec.vcls, latsel,
+            dec.kind, dec.vcls,
             (dec.id_lui, dec.id_addi, dec.id_cfg, dec.id_cfgr,
              dec.id_setvl), n_regs)
     return got
@@ -341,9 +306,9 @@ def _call_exec(fn: Any, sc: Tuple[np.ndarray, ...],
     """Upload, run (under x64), download, and un-pad one device call."""
     import warnings
 
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
-    with enable_x64(), warnings.catch_warnings():
+    with jax.enable_x64(True), warnings.catch_warnings():
         # donation is best-effort: a couple of int64 columns have no
         # matching output shape — harmless, not worth a user warning
         warnings.filterwarnings(
@@ -357,17 +322,23 @@ def _call_exec(fn: Any, sc: Tuple[np.ndarray, ...],
 
 
 def _finish_from_device(out: _DecodedStage, pr: _Prep, dec: StageDecoder,
-                        m: MachineModel, lat: np.ndarray,
+                        m: MachineModel, ilat: np.ndarray,
                         res: Dict[str, np.ndarray]) -> None:
-    """Numpy back half: event ledgers, boundary items, replay plan.
+    """Numpy back half: float latencies, event ledgers, boundary items,
+    replay plan.
 
-    Every expression here is copied verbatim from the numpy engine's
-    ``decode_stage`` (same dtypes, same `.sum()` order), so the totals
-    are bit-identical given identical per-instruction inputs.
+    ``ilat`` holds the device's int64 vector / MVM latencies; the rest
+    are priced here.  Every expression is copied verbatim from the
+    numpy engine's ``decode_stage`` (same dtypes, same `.sum()` order),
+    so the totals are bit-identical given identical per-instruction
+    inputs.
     """
     op, kind, col = pr.op, pr.kind, pr.col
     ev_tot = [0.0] * 4
     ev_cnt = [0] * 4
+    lat = dec.clat[op].copy()
+    dpos = np.flatnonzero((kind == _K_VEC) | (kind == _K_MVM))
+    lat[dpos] = ilat[dpos]                # int64 -> float64, exact < 2^53
 
     # ---- S_LD / S_ST ledger traffic (4 B words) --------------------
     n_mem = int(((op == dec.id_sld) | (op == dec.id_sst)).sum())
@@ -389,6 +360,7 @@ def _finish_from_device(out: _DecodedStage, pr: _Prep, dec: StageDecoder,
     lpos = np.flatnonzero(kind == _K_WLOAD)
     if len(lpos):
         rows = col("rows")[lpos]
+        lat[lpos] = m.weight_load_cycles_array(rows)
         nlen = np.maximum(res["nlen"][lpos], 1)
         wl = float((rows * nlen).sum())
         ev_tot[0] += wl
@@ -411,6 +383,11 @@ def _finish_from_device(out: _DecodedStage, pr: _Prep, dec: StageDecoder,
         ev_tot[0] += float((rep * seg).sum())
         ev_cnt[0] += len(mpos)
         ev_cnt[2] += len(mpos)
+
+    # ---- BCAST: sender-side injection occupancy --------------------
+    bcast = np.flatnonzero(kind == _K_BCAST)
+    if len(bcast):
+        lat[bcast] = m.send_issue_cycles_array(res["size"][bcast])
 
     # ---- boundary items --------------------------------------------
     bitems: Dict[int, tuple] = {}

@@ -498,16 +498,17 @@ class MachineModel:
     # Batched-decode constants (JAX engine / fleet evaluation)
     # ------------------------------------------------------------------
 
-    def timing_constants(self) -> Dict[str, float]:
-        """The scalar timing constants of the batchable decode subset.
+    def timing_constants(self) -> Dict[str, int]:
+        """The integer timing constants of the batchable decode subset.
 
-        These are the *only* machine numbers the static stage-decode
-        latency pass reads (:mod:`repro.core.jaxsim`); stacking them
-        across machines yields the vmappable table pytree one XLA
-        program evaluates for a whole fleet of chip variants ("same
-        program, different chip constants").  Integer-valued entries
-        stay exact ints so the batched arithmetic is bit-identical to
-        the per-machine accessors above.
+        These are the *only* machine numbers the device's static
+        stage-decode latency pass reads (:mod:`repro.core.jaxsim`);
+        stacking them across machines yields the vmappable table pytree
+        one XLA program evaluates for a whole fleet of chip variants
+        ("same program, different chip constants").  They are exact
+        ints, so the batched arithmetic is bit-identical to the
+        per-machine accessors above; the float latencies are priced on
+        the host.
         """
         v = self.chip.core.vector
         return {
@@ -517,11 +518,6 @@ class MachineModel:
             "vector_special_latency": int(v.special_latency),
             "mvm_interval_beats": int(self.mvm_interval_beats),
             "mvm_fill_beats": int(self.mvm_fill_beats),
-            "scalar_alu_cycles": float(self.scalar_alu_cycles),
-            "scalar_ldst_cycles": float(self.scalar_ldst_cycles),
-            "weight_load_rows_per_cycle": float(
-                self.effective_weight_load_rows_per_cycle),
-            "link_bytes_per_cycle": float(self.link_bytes_per_cycle),
         }
 
     # ------------------------------------------------------------------

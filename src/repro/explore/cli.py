@@ -198,7 +198,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     help="directory for the persistent flow "
                          "pass-output cache (shared by pool workers)")
     sw.add_argument("--pool", type=int, default=0,
-                    help="worker processes (0 = serial)")
+                    help="worker processes (0 = serial; ignored "
+                         "with --engine jax, which runs in-process)")
     sw.add_argument("--engine",
                     choices=("auto", "scalar", "vector", "jax"),
                     default="auto",

@@ -198,6 +198,8 @@ class ExplorationEngine:
         Workload name from :data:`repro.core.workloads.WORKLOADS`.
     pool:
         Worker processes; ``0``/``1`` evaluates serially in-process.
+        ``engine="jax"`` always evaluates in-process: its device
+        runtime cannot be shared with forked workers.
     cache:
         ``ResultCache`` instance, a directory path, or ``None`` to
         disable caching entirely.
@@ -412,7 +414,10 @@ class ExplorationEngine:
                     rest = [k for k in rest if k not in taken]
             sub = [jobs[k] for k in rest]
             if sub:
-                if self.pool > 1 and len(sub) > 1:
+                # the jax engine's fleet holds the device runtime in this
+                # process; a forked worker would inherit a chip it cannot
+                # use (it fails or hangs), so that engine never forks
+                if self.pool > 1 and len(sub) > 1 and self.engine != "jax":
                     got = self._run_pool(sub, fidelity)
                 else:
                     _WORKER["cg"] = self.cg   # built once per engine

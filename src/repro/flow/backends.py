@@ -190,12 +190,11 @@ class PallasFuncBackend(Backend):
     :func:`repro.core.ref.run_reference`, executing every INT8 matmul
     on :func:`repro.kernels.ops.cim_mvm` — the bit-serial bit-plane
     decomposition a digital CIM macro performs, as a Pallas kernel
-    (interpret mode on CPU, native on TPU; see
-    ``REPRO_PALLAS_INTERPRET``).  With ``check=True`` (default) the
-    pure-numpy oracle runs alongside and every group output is asserted
-    bit-equal, so one evaluation validates the kernel's integer
-    semantics at full-model scale — feasible where the per-instruction
-    functional ISS is not (e.g. resnet18 at 224x224).
+    (interpret mode off a TPU, native on a TPU).  With ``check=True``
+    (default) the pure-numpy oracle runs alongside and every group
+    output is asserted bit-equal, so one evaluation validates the
+    kernel's integer semantics at full-model scale — feasible where the
+    per-instruction functional ISS is not (e.g. resnet18 at 224x224).
 
     ``weights``/``biases``/``inputs``/``quant`` default to
     :func:`repro.core.ref.random_init` + ``auto_quant`` draws, making

@@ -37,10 +37,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["bitserial_mvm_kernel", "bitserial_mvm_pallas"]
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def bitserial_mvm_kernel(x_ref, w_ref, o_ref, acc_ref, *, act_bits: int,
                          k_steps: int, signed: bool) -> None:
@@ -101,7 +97,7 @@ def bitserial_mvm_pallas(x: jax.Array, w: jax.Array, *, act_bits: int = 8,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w)

@@ -8,40 +8,30 @@
   arithmetic inside and a straight-through-estimator custom VJP, used by
   the framework's quantization-aware training / INT8 serving path.
 
-On CPU (this container) the Pallas kernel runs in ``interpret=True``;
-on TPU it compiles natively.  ``interpret=None`` auto-detects.
+Off a TPU the Pallas kernel runs in ``interpret=True``; on a TPU it
+compiles natively.  ``interpret=None`` picks from the backend alone.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from ..compile_cache import enable_compile_cache
 from .bitserial_mvm import bitserial_mvm_pallas
 from .ref import mvm_ref
 
 __all__ = ["cim_mvm", "int8_matmul", "quantized_linear", "pad_to"]
 
-_INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
-
 
 @functools.lru_cache(maxsize=None)
 def _auto_interpret() -> bool:
-    """Whether Pallas kernels should run in interpret mode by default.
-
-    ``REPRO_PALLAS_INTERPRET=0/1`` overrides the backend probe (e.g. to
-    force interpret mode on a TPU host, or assert native compilation).
-    Memoized — ``jax.default_backend()`` initializes the platform
-    backend, which is milliseconds per call; tests monkeypatching the
-    env var must ``_auto_interpret.cache_clear()``.
-    """
-    env = os.environ.get(_INTERPRET_ENV)
-    if env is not None and env.strip() != "":
-        return env.strip().lower() not in ("0", "false", "no", "off")
+    """Whether Pallas kernels run in interpret mode: on every backend
+    but the TPU.  Memoized — ``jax.default_backend()`` initializes the
+    platform backend, which is milliseconds per call."""
     return jax.default_backend() != "tpu"
 
 
@@ -64,6 +54,7 @@ def cim_mvm(x: jax.Array, w: jax.Array, *, act_bits: int = 8,
             signed: bool = True,
             interpret: Optional[bool] = None) -> jax.Array:
     """Bit-serial CIM MVM, ragged shapes welcome: int8 x int8 -> int32."""
+    enable_compile_cache()               # traced before each compile
     if interpret is None:
         interpret = _auto_interpret()
     m, k = x.shape

@@ -9,12 +9,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:                              # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:               # jax 0.4.x: meshes are Auto-only
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 __all__ = ["make_production_mesh", "make_mesh", "data_axes_of",
            "MODEL_AXIS"]
@@ -23,10 +18,8 @@ MODEL_AXIS = "model"
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -246,7 +246,6 @@ def test_transformer_func_matches_jax_reference():
     checked against the functional ISS output of the compiled model.
     """
     jax = pytest.importorskip("jax")
-    from jax.experimental import enable_x64
     import jax.numpy as jnp
     from repro.core import vecsem
 
@@ -265,7 +264,7 @@ def test_transformer_func_matches_jax_reference():
         model, gmem_image=img)
 
     gid = {grp.name: grp.idx for grp in cg}
-    with enable_x64():
+    with jax.enable_x64(True):
         EXP2 = jnp.asarray(vecsem.EXP2_LUT)
         GELU = jnp.asarray(vecsem.GELU_LUT)
 

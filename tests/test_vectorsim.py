@@ -451,8 +451,9 @@ if HAVE_HYPOTHESIS:
                        I("BCAST", src=1, size=8)],
         ])
 
-        class _D:
-            draw = staticmethod(draw)
+        # a class body cannot read the enclosing ``draw`` while
+        # binding a name of its own to it, so build the namespace here
+        _D = type("_D", (), {"draw": staticmethod(draw)})
 
         n_phases = draw(st.integers(1, 2))
         chunks = {c: [] for c in range(_N_CORES)}
