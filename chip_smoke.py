@@ -23,10 +23,13 @@ process on one chip, phase by phase:
 Each phase prints one line with its wall time on the chip machine's
 host clock, and the XLA compile time inside it where it compiles
 (compile times are read from JAX's own compile events, so a persistent
-cache hit shows as a short retrieval).  The stage-pass device calls
-are observed by wrapping ``jaxsim._call_exec``: each must download
-integers only (no float64 is computed on the chip), and the
-stage-pass compiles are listed per decode-table bucket.  The persistent compile cache sits in
+cache hit shows as a short retrieval).  The simulate and fleet phases
+run under ``jax.profiler`` (python tracer off), and their stage-pass
+device calls are read from the program's own ``repro.sim.device_call``
+spans, whose counters give each call's decode rows, bucket, register
+bucket and machines (the traces stay in ``results/chip_smoke_trace/``
+for TensorBoard or Perfetto); the stage-pass compiles are listed per
+decode-table bucket.  The persistent compile cache sits in
 ``$JAX_COMPILATION_CACHE_DIR`` or ``results/jax_cache`` (see
 ``repro.compile_cache``), so a second run skips them.
 
@@ -37,22 +40,24 @@ standard output is one JSON object naming the device.
 
 from __future__ import annotations
 
+import contextlib
+import glob
 import json
 import os
+import shutil
 import sys
 import time
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, Iterator, List, Tuple
 
 import numpy as np
 
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                "src"))
+ROOT = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.join(ROOT, "src"))
 
 import jax                                               # noqa: E402
 
 from repro import flow                                   # noqa: E402
 from repro.compile_cache import enable_compile_cache     # noqa: E402
-from repro.core import jaxsim                            # noqa: E402
 from repro.core.arch import default_chip                 # noqa: E402
 from repro.core.mapping import CostParams                # noqa: E402
 from repro.core.simulator import Simulator               # noqa: E402
@@ -71,6 +76,7 @@ KERNEL_SHAPE = (3136, 1152, 128)
 FLEET_CHECKS = (0, 21, 42, 63)          # timing_space() indices re-run
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+DEVICE_CALL = "repro.sim.device_call"
 
 
 class SmokeFailure(RuntimeError):
@@ -155,36 +161,32 @@ def phase_kernel(log: CompileLog) -> None:
                 f"{second:.6f} s")
 
 
-class DeviceCalls:
-    """Every stage-pass device call, seen by wrapping
-    ``jaxsim._call_exec`` (module-global, looked up at each call).
+@contextlib.contextmanager
+def device_calls(phase: str) -> Iterator[List[Tuple[int, int, int, int]]]:
+    """Trace the body and fill the list it binds, after it, with every
+    stage-pass device call the program made in it, in order, as
+    ``(decode rows, padded bucket, register bucket, machines)`` read
+    from the ``repro.sim.device_call`` spans' counters."""
+    from jax.profiler import ProfileData
 
-    Each entry is ``(decode rows, padded bucket, register bucket,
-    machines)``.  Every array a call downloads must be integer: the
-    float latencies are priced on the host, since the chip's emulated
-    float64 is not IEEE-exact.
-    """
-
-    def __init__(self) -> None:
-        self.calls: List[Tuple[int, int, int, int]] = []
-        inner = jaxsim._call_exec
-
-        def observed(fn, sc, cols, n):
-            lat, res = inner(fn, sc, cols, n)
-            floats = [k for k, v in [("lat", lat), *res.items()]
-                      if not np.issubdtype(v.dtype, np.integer)]
-            _check(not floats, f"stage pass downloaded non-integer "
-                               f"outputs {floats}")
-            nregs = next(key[-1] for key, fns in jaxsim._EXEC_CACHE.items()
-                         if fn in fns)
-            self.calls.append((n, len(cols["op"]), nregs,
-                               lat.shape[0] if lat.ndim == 2 else 1))
-            return lat, res
-
-        jaxsim._call_exec = observed
-
-    def since(self, mark: int) -> List[Tuple[int, int, int, int]]:
-        return self.calls[mark:]
+    trace_dir = os.path.join(ROOT, "results", "chip_smoke_trace", phase)
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    calls: List[Tuple[int, int, int, int]] = []
+    with jax.profiler.trace(trace_dir, profiler_options=opts):
+        yield calls
+    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    _check(bool(files), f"{phase}: the profiler wrote no trace")
+    pd = ProfileData.from_file(max(files, key=os.path.getmtime))
+    spans = sorted(((e.start_ns, dict(e.stats))
+                    for plane in pd.planes if plane.name == "/host:CPU"
+                    for line in plane.lines for e in line.events
+                    if e.name == DEVICE_CALL), key=lambda x: x[0])
+    calls.extend(tuple(int(st[k]) for k in ("rows", "bucket", "regs",
+                                            "machines"))
+                 for _, st in spans)
 
 
 def _bucket_report(label: str, calls, compiles) -> None:
@@ -213,7 +215,7 @@ def _same_report(label: str, jx: Any, vec: Any) -> None:
                        f"engine {b!r}")
 
 
-def phase_simulate(log: CompileLog, dev: DeviceCalls) -> None:
+def phase_simulate(log: CompileLog) -> None:
     chip = default_chip()
     t0 = time.perf_counter()
     art = flow.compile(MODEL, chip, CompileOptions(
@@ -222,11 +224,12 @@ def phase_simulate(log: CompileLog, dev: DeviceCalls) -> None:
     cm = art.ensure_model()
     host_compile = time.perf_counter() - t0
 
-    mark, dmark = len(log.entries), len(dev.calls)
-    t1 = time.perf_counter()
-    jx = Simulator(chip, cm.isa, engine="jax").run_model(cm)
-    jax_first = time.perf_counter() - t1
-    compiles, calls = log.since(mark), dev.since(dmark)
+    mark = len(log.entries)
+    with device_calls("simulate") as calls:
+        t1 = time.perf_counter()
+        jx = Simulator(chip, cm.isa, engine="jax").run_model(cm)
+        jax_first = time.perf_counter() - t1
+    compiles = log.since(mark)
     for i, (n, nb, nregs, _) in enumerate(calls):
         print(f"  device call {i}: {n} decode rows -> bucket {nb} x "
               f"{nregs}", flush=True)
@@ -251,7 +254,7 @@ def phase_simulate(log: CompileLog, dev: DeviceCalls) -> None:
     _bucket_report("single", calls, compiles)
 
 
-def phase_fleet(log: CompileLog, dev: DeviceCalls) -> None:
+def phase_fleet(log: CompileLog) -> None:
     points = list(timing_space().points())
     _check(len(points) == 64, f"timing_space() has {len(points)} points")
     canon = canonical_chip(points[0].chip())
@@ -259,11 +262,12 @@ def phase_fleet(log: CompileLog, dev: DeviceCalls) -> None:
            "timing_space() spans more than one compiled program")
     params = CostParams(batch=BATCH)
     eng = ExplorationEngine(MODEL, params=params, engine="jax", res=RES)
-    mark, dmark = len(log.entries), len(dev.calls)
-    t0 = time.perf_counter()
-    recs = eng.evaluate(points, fidelity="simulate")
-    wall = time.perf_counter() - t0
-    compiles, calls = log.since(mark), dev.since(dmark)
+    mark = len(log.entries)
+    with device_calls("fleet") as calls:
+        t0 = time.perf_counter()
+        recs = eng.evaluate(points, fidelity="simulate")
+        wall = time.perf_counter() - t0
+    compiles = log.since(mark)
     bad = [r.error for r in recs if not r.ok]
     _check(not bad, f"fleet: {len(bad)} records failed, first: "
                     f"{bad[:1]}")
@@ -323,10 +327,10 @@ def main() -> int:
                 if os.path.isdir(cache_dir) else 0)
     print(f"compile cache: {cache_dir} ({n_cached} entries at start)",
           flush=True)
-    log, dev = CompileLog(), DeviceCalls()
+    log = CompileLog()
     phase_kernel(log)
-    phase_simulate(log, dev)
-    phase_fleet(log, dev)
+    phase_simulate(log)
+    phase_fleet(log)
     phase_pallas(log)
     print(f"total chip wall {time.perf_counter() - t0:.3f} s", flush=True)
     print(json.dumps({"ok": True, "device": device}), flush=True)

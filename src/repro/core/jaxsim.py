@@ -50,6 +50,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..obs import span
 from .isa import Isa, Program
 from .machine import MachineModel
 from .vectorsim import (
@@ -98,9 +99,11 @@ class MachineTables:
 
     @classmethod
     def stack(cls, machines: List[MachineModel]) -> "MachineTables":
-        rows = [m.timing_constants() for m in machines]
-        return cls(tuple(np.array([r[k] for r in rows], dtype=np.int64)
-                         for k in _INT_KEYS))
+        with span("repro.sim.decode_prep", machines=len(machines)):
+            rows = [m.timing_constants() for m in machines]
+            return cls(tuple(np.array([r[k] for r in rows],
+                                      dtype=np.int64)
+                             for k in _INT_KEYS))
 
 
 def _scalar_row(tc: Dict[str, int]) -> Tuple[np.ndarray, ...]:
@@ -120,6 +123,9 @@ def _bucket(n: int, lo: int = 8) -> int:
 # ---------------------------------------------------------------------------
 
 _EXEC_CACHE: Dict[tuple, Tuple[Any, Any]] = {}
+# the register bucket each compiled stage pass was built for, by
+# function (a device call's ``regs`` counter)
+_EXEC_REGS: Dict[Any, int] = {}
 
 
 def _build_exec(kind_t: np.ndarray, vcls_t: np.ndarray,
@@ -241,6 +247,7 @@ def _build_exec(kind_t: np.ndarray, vcls_t: np.ndarray,
     single = jax.jit(stage_pass, donate_argnums=(1,))
     fleet = jax.jit(jax.vmap(stage_pass, in_axes=(0, None),
                              out_axes=(0, None)))
+    _EXEC_REGS[single] = _EXEC_REGS[fleet] = n_regs
     return single, fleet
 
 
@@ -308,7 +315,12 @@ def _call_exec(fn: Any, sc: Tuple[np.ndarray, ...],
 
     import jax
     import jax.numpy as jnp
-    with jax.enable_x64(True), warnings.catch_warnings():
+    up = (sum(v.nbytes for v in cols.values())
+          + sum(np.asarray(x).nbytes for x in sc))
+    with span("repro.sim.device_call", rows=n, bucket=len(cols["op"]),
+              regs=_EXEC_REGS[fn], machines=int(np.size(sc[0])),
+              bytes_up=up) as sp, \
+            jax.enable_x64(True), warnings.catch_warnings():
         # donation is best-effort: a couple of int64 columns have no
         # matching output shape — harmless, not worth a user warning
         warnings.filterwarnings(
@@ -317,7 +329,10 @@ def _call_exec(fn: Any, sc: Tuple[np.ndarray, ...],
         dev_sc = tuple(jnp.asarray(x) for x in sc)
         lat, res = fn(dev_sc, dev_cols)
         lat = np.asarray(lat)
-        res = {k: np.asarray(v)[:n] for k, v in res.items()}
+        res = {k: np.asarray(v) for k, v in res.items()}
+        sp.set_metadata(bytes_down=lat.nbytes
+                        + sum(v.nbytes for v in res.values()))
+        res = {k: v[:n] for k, v in res.items()}
     return lat[..., :n], res
 
 
@@ -333,6 +348,13 @@ def _finish_from_device(out: _DecodedStage, pr: _Prep, dec: StageDecoder,
     so the totals are bit-identical given identical per-instruction
     inputs.
     """
+    with span("repro.sim.finish"):
+        _finish(out, pr, dec, m, ilat, res)
+
+
+def _finish(out: _DecodedStage, pr: _Prep, dec: StageDecoder,
+            m: MachineModel, ilat: np.ndarray,
+            res: Dict[str, np.ndarray]) -> None:
     op, kind, col = pr.op, pr.kind, pr.col
     ev_tot = [0.0] * 4
     ev_cnt = [0] * 4
@@ -425,21 +447,25 @@ class JaxStageDecoder:
     def __init__(self, isa: Isa, m: MachineModel) -> None:
         self.isa = isa
         self.m = m
-        self.npdec = StageDecoder(isa, m)
-        self._sc = _scalar_row(m.timing_constants())
+        with span("repro.sim.decode_prep", machines=1):
+            self.npdec = StageDecoder(isa, m)
+            self._sc = _scalar_row(m.timing_constants())
 
     def decode_stage(self, programs: Dict[int, Program]) -> _DecodedStage:
         out = _DecodedStage()
-        pr = self.npdec._prep(programs)
-        out.n_prog = pr.n_prog
-        for cid in pr.empty:
-            out.items[cid] = [(_END,)]
-        for cid, prog in pr.unroll:
-            self.npdec.unroll_decode(prog, cid, out)
-        if not pr.cids:
-            return out
-        fn, _ = _exec_for(self.npdec, _reg_bucket(pr))
-        lat, res = _call_exec(fn, self._sc, _device_cols(pr), pr.n)
+        with span("repro.sim.decode_prep", machines=1) as sp:
+            pr = self.npdec._prep(programs)
+            sp.set_metadata(rows=pr.n)
+            out.n_prog = pr.n_prog
+            for cid in pr.empty:
+                out.items[cid] = [(_END,)]
+            for cid, prog in pr.unroll:
+                self.npdec.unroll_decode(prog, cid, out)
+            if not pr.cids:
+                return out
+            fn, _ = _exec_for(self.npdec, _reg_bucket(pr))
+            cols = _device_cols(pr)
+        lat, res = _call_exec(fn, self._sc, cols, pr.n)
         _finish_from_device(out, pr, self.npdec, self.m, lat, res)
         return out
 
@@ -456,29 +482,38 @@ class FleetStageDecoder:
     def __init__(self, isa: Isa, machines: List[MachineModel]) -> None:
         self.isa = isa
         self.machines = list(machines)
-        self.npdecs = [StageDecoder(isa, m) for m in self.machines]
+        with span("repro.sim.decode_prep", machines=len(self.machines)):
+            self.npdecs = [StageDecoder(isa, m) for m in self.machines]
         self.tables = MachineTables.stack(self.machines)
 
     def prep(self, programs: Dict[int, Program]) -> _Prep:
         """Machine-independent front half (cacheable by the caller)."""
-        return self.npdecs[0]._prep(programs)
+        with span("repro.sim.decode_prep",
+                  machines=len(self.machines)) as sp:
+            pr = self.npdecs[0]._prep(programs)
+            sp.set_metadata(rows=pr.n)
+        return pr
 
     def decode_stage(self, programs: Dict[int, Program],
                      prep: Optional[_Prep] = None) -> List[_DecodedStage]:
         pr = prep if prep is not None else self.prep(programs)
+        n = len(self.machines)
         lat = res = None
         if pr.cids:
-            _, fleet_fn = _exec_for(self.npdecs[0], _reg_bucket(pr))
-            lat, res = _call_exec(fleet_fn, self.tables.arrays,
-                                  _device_cols(pr), pr.n)
+            with span("repro.sim.decode_prep", rows=pr.n, machines=n):
+                _, fleet_fn = _exec_for(self.npdecs[0], _reg_bucket(pr))
+                cols = _device_cols(pr)
+            lat, res = _call_exec(fleet_fn, self.tables.arrays, cols, pr.n)
         outs: List[_DecodedStage] = []
         for i, (m, dec) in enumerate(zip(self.machines, self.npdecs)):
             out = _DecodedStage()
             out.n_prog = dict(pr.n_prog)
             for cid in pr.empty:
                 out.items[cid] = [(_END,)]
-            for cid, prog in pr.unroll:
-                dec.unroll_decode(prog, cid, out)
+            if pr.unroll:
+                with span("repro.sim.decode_prep", rows=pr.n, machines=1):
+                    for cid, prog in pr.unroll:
+                        dec.unroll_decode(prog, cid, out)
             if pr.cids:
                 _finish_from_device(out, pr, dec, m, lat[i], res)
             outs.append(out)

@@ -63,6 +63,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..obs import span
 from .isa import Isa, Program, SREG
 from .machine import MachineModel
 
@@ -862,6 +863,12 @@ def replay_stage(sim: Any, sp: Any,
     ``sim`` only needs ``.m`` and ``.max_cycles`` — the fleet evaluator
     passes a lightweight shim instead of a full ``Simulator``.
     """
+    with span("repro.sim.replay"):
+        return _replay(sim, sp, ds)
+
+
+def _replay(sim: Any, sp: Any, ds: _DecodedStage
+            ) -> Tuple[float, Dict[str, float], Dict[str, float], int]:
     from .simulator import Deadlock, SimError     # late: avoid cycle
     m = sim.m
     max_cycles = sim.max_cycles

@@ -51,6 +51,7 @@ from ..core.machine import MachineModel, energy_breakdown, machine_for
 from ..core.mapping import CostParams
 from ..core.vectorsim import DecodeUnsupported, replay_stage
 from ..flow import CompileOptions
+from ..obs import span
 
 __all__ = ["canonical_chip", "FleetEvaluator"]
 
@@ -163,9 +164,11 @@ class FleetEvaluator:
             groups.setdefault(key, []).append(i)
         for (canon, strategy), idxs in groups.items():
             chips = [jobs[i][0] for i in idxs]
-            for i, payload in zip(idxs,
-                                  self._eval_group(canon, strategy,
-                                                   chips)):
+            # around the call, so the release of the group's replay
+            # plans as it returns is inside the span too
+            with span("repro.explore.fleet", machines=len(chips)):
+                payloads = self._eval_group(canon, strategy, chips)
+            for i, payload in zip(idxs, payloads):
                 results[i] = payload
         return results           # type: ignore[return-value]
 

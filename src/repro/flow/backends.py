@@ -34,6 +34,7 @@ import numpy as np
 from ..core.machine import machine_for
 from ..core.simulator import ENGINES, SimReport, Simulator
 from ..core.trace import TraceEngine, TraceReport
+from ..obs import span
 
 __all__ = ["EvalReport", "Backend", "AnalyticBackend", "TraceBackend",
            "SimulatorBackend", "PallasFuncBackend", "BACKENDS",
@@ -230,18 +231,21 @@ class PallasFuncBackend(Backend):
         # ``faults`` (a repro.faults.FaultSet) corrupts both oracles
         # identically, so the bit-equality check stays meaningful on
         # faulty runs — it validates the kernel, not the fault model
-        outs = ref.run_reference(cg, weights, biases, quant, inputs,
-                                 matmul=_pallas_matmul, faults=faults)
+        with span("repro.ref.forward"):
+            outs = ref.run_reference(cg, weights, biases, quant, inputs,
+                                     matmul=_pallas_matmul, faults=faults)
         if check:
-            want = ref.run_reference(cg, weights, biases, quant, inputs,
-                                     faults=faults)
-            for gid, arr in want.items():
-                got = outs[gid]
-                if got.shape != arr.shape or not np.array_equal(got, arr):
-                    raise AssertionError(
-                        f"func:pallas mismatch on group {gid}: pallas "
-                        f"oracle != numpy oracle "
-                        f"(shapes {got.shape} vs {arr.shape})")
+            with span("repro.ref.oracle"):
+                want = ref.run_reference(cg, weights, biases, quant,
+                                         inputs, faults=faults)
+                for gid, arr in want.items():
+                    got = outs[gid]
+                    if got.shape != arr.shape or not np.array_equal(got,
+                                                                    arr):
+                        raise AssertionError(
+                            f"func:pallas mismatch on group {gid}: pallas "
+                            f"oracle != numpy oracle "
+                            f"(shapes {got.shape} vs {arr.shape})")
         # a functional-validation pass carries no timing claim
         return EvalReport(backend=self.name, cycles=0.0,
                           energy={"total": 0.0}, throughput_sps=0.0,
@@ -254,8 +258,10 @@ def _pallas_matmul(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     import jax.numpy as jnp
 
     from ..kernels.ops import cim_mvm
-    return np.asarray(cim_mvm(jnp.asarray(a, jnp.int8),
-                              jnp.asarray(b, jnp.int8)))
+    (m, k), n = a.shape, b.shape[1]
+    with span("repro.kernel.cim_mvm", m=m, k=k, n=n):
+        return np.asarray(cim_mvm(jnp.asarray(a, jnp.int8),
+                                  jnp.asarray(b, jnp.int8)))
 
 
 BACKENDS: Dict[str, Backend] = {}

@@ -34,6 +34,7 @@ from ..core.arch import ChipConfig
 from ..core.codegen import CompiledModel
 from ..core.graph import CondensedGraph, Graph
 from ..core.partition import PartitionResult
+from ..obs import span
 from .backends import Backend, EvalReport, resolve_backend
 from .diskcache import ENV_VAR as _CACHE_ENV
 from .diskcache import PassDiskCache
@@ -270,21 +271,25 @@ class Pipeline:
         subset = ctx.options.subset_key(p.depends)
         key = hashlib.sha256(
             f"{prev_key}|{p.name}|{subset}".encode()).hexdigest()
-        t0 = time.perf_counter()
-        cached, out = (self._cache_get(key) if p.cacheable
-                       else (False, None))
-        if not cached:
-            out = p.run(ctx)
-            if p.cacheable:
-                self._cache_put(key, out)
-        dump_path = None
-        if ctx.options.dump_dir:      # dump cache hits too — the dump
-            dump_path = p.write_dump(out, ctx.options.dump_dir, key)
-            # dir may differ from (or postdate) the run that filled
-            # the cache
-        p.apply(ctx, out)
-        rec = PassRecord(name=p.name,
-                         wall_s=time.perf_counter() - t0,
+        # the span and ``wall_s`` time one interval: ``describe()`` and
+        # a profiler trace agree on what the pass cost
+        with span(f"repro.flow.{p.name}") as sp:
+            t0 = time.perf_counter()
+            cached, out = (self._cache_get(key) if p.cacheable
+                           else (False, None))
+            sp.set_metadata(cached=cached)
+            if not cached:
+                out = p.run(ctx)
+                if p.cacheable:
+                    self._cache_put(key, out)
+            dump_path = None
+            if ctx.options.dump_dir:  # dump cache hits too — the dump
+                dump_path = p.write_dump(out, ctx.options.dump_dir, key)
+                # dir may differ from (or postdate) the run that filled
+                # the cache
+            p.apply(ctx, out)
+            wall_s = time.perf_counter() - t0
+        rec = PassRecord(name=p.name, wall_s=wall_s,
                          cached=cached, summary=p.summarize(out),
                          key=key[:16], dump_path=dump_path)
         return out, rec, key

@@ -323,6 +323,31 @@ def test_fleet_stage_decoder_matches_per_machine():
         assert_identical(out_s, out_f)
 
 
+def test_device_call_downloads_integers_only():
+    """Every array a stage-pass device call brings back is integer, on
+    the single and the vmapped program: the float latencies are priced
+    on the host, since a TPU's emulated float64 is not IEEE-exact."""
+    cm = flow.compile("tiny_cnn", CHIP, flow.CompileOptions(
+        params=CostParams(batch=2), fidelity="simulate")).ensure_model()
+    machines = [machine_for(c) for c in _timing_chips(3)]
+    fleet = jaxsim.FleetStageDecoder(cm.isa, machines)
+    for sp in cm.stages:
+        pr = fleet.prep(sp.programs)
+        single_fn, fleet_fn = jaxsim._exec_for(fleet.npdecs[0],
+                                               jaxsim._reg_bucket(pr))
+        for fn, sc, want in (
+                (single_fn, jaxsim._scalar_row(
+                    machines[0].timing_constants()), (pr.n,)),
+                (fleet_fn, fleet.tables.arrays, (3, pr.n))):
+            lat, res = jaxsim._call_exec(fn, sc, jaxsim._device_cols(pr),
+                                         pr.n)
+            assert lat.shape == want
+            got = {"lat": lat, **res}
+            assert all(np.issubdtype(v.dtype, np.integer)
+                       for v in got.values()), {k: v.dtype
+                                                for k, v in got.items()}
+
+
 # ---------------------------------------------------------------------------
 # ExplorationEngine(engine="jax")
 # ---------------------------------------------------------------------------
