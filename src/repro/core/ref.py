@@ -35,13 +35,48 @@ from .graph import CondensedGraph, Graph
 from .oplevel import Im2colSpec
 
 __all__ = ["conv_weight_matrix", "dwconv_weight_matrix", "im2col",
-           "quantize", "run_reference", "auto_quant", "random_init"]
+           "quantize", "run_reference", "auto_quant", "random_init",
+           "exact_matmul", "ContractionTally"]
 
 # the INT8 x INT8 -> INT32 accumulator contraction; swappable so the
 # same oracle can execute its MVMs on an accelerator kernel (see
 # ``flow.backends.PallasFuncBackend``) while everything around the
-# matmul stays pure numpy
+# matmul stays pure numpy.  Operand values always fit int8, so each
+# product is at most 2**14 in magnitude and a K-term sum at most
+# K * 2**14: exact in float64 for any K below 2**39.  The default
+# (:func:`exact_matmul`) therefore runs the contraction as a float64
+# BLAS GEMM and returns exactly the integers numpy's int32 ``@`` would.
 MatmulFn = Callable[[np.ndarray, np.ndarray], np.ndarray]
+
+
+def exact_matmul(a: np.ndarray, b: np.ndarray) -> np.ndarray:
+    """``(M, K) x (K, N) -> (M, N)`` int32, bit-equal to int32 ``a @ b``.
+
+    Operand values fit int8 (the :data:`MatmulFn` contract), so every
+    product is at most 2**14 in magnitude and the sum of K of them at
+    most K * 2**14, exact in float64 (whose integers are exact up to
+    2**53) for any K below 2**39.  So casting both operands to float64,
+    multiplying with BLAS and casting back through int64 gives the true
+    sum modulo 2**32: exactly the integers numpy's int32 ``@`` gives
+    (which has no BLAS and runs a scalar loop), its wraparound at
+    K >= 2**17 included.
+    """
+    return (a.astype(np.float64) @ b.astype(np.float64)
+            ).astype(np.int64).astype(np.int32)
+
+
+class ContractionTally:
+    """:func:`exact_matmul` that counts its calls: the ``f64`` counter
+    of the ``repro.ref.oracle`` span."""
+
+    __slots__ = ("f64",)
+
+    def __init__(self) -> None:
+        self.f64 = 0
+
+    def __call__(self, a: np.ndarray, b: np.ndarray) -> np.ndarray:
+        self.f64 += 1
+        return exact_matmul(a, b)
 
 
 def conv_weight_matrix(kernel: np.ndarray) -> np.ndarray:
@@ -122,7 +157,8 @@ def run_reference(cg: CondensedGraph, weights: Dict[int, np.ndarray],
 
     ``matmul`` overrides the accumulator contraction
     ``(M, K) int32 x (K, N) int32 -> (M, N) int32`` (operand *values*
-    always fit int8); the default is the numpy ``@``.
+    always fit int8); the default is :func:`exact_matmul`, bit-equal
+    to numpy's int32 ``@``.
 
     ``faults`` is an optional :class:`repro.faults.FaultSet`: static
     weight matrices are stuck-at-corrupted before the contraction and
@@ -132,8 +168,7 @@ def run_reference(cg: CondensedGraph, weights: Dict[int, np.ndarray],
     build their matrix from activations at run time and carry no
     stored-weight faults.
     """
-    mm: MatmulFn = matmul if matmul is not None else (
-        lambda a, b: a @ b)
+    mm: MatmulFn = matmul if matmul is not None else exact_matmul
     src = cg.source
     assert src is not None, "reference needs the source graph"
     op_owner = {}

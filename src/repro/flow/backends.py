@@ -235,9 +235,12 @@ class PallasFuncBackend(Backend):
             outs = ref.run_reference(cg, weights, biases, quant, inputs,
                                      matmul=_pallas_matmul, faults=faults)
         if check:
-            with span("repro.ref.oracle"):
+            with span("repro.ref.oracle") as sp:
+                tally = ref.ContractionTally()
                 want = ref.run_reference(cg, weights, biases, quant,
-                                         inputs, faults=faults)
+                                         inputs, matmul=tally,
+                                         faults=faults)
+                sp.set_metadata(f64=tally.f64)
                 for gid, arr in want.items():
                     got = outs[gid]
                     if got.shape != arr.shape or not np.array_equal(got,

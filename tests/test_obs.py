@@ -121,3 +121,17 @@ def test_pass_record_times_the_flow_span(tmp_path):
     for rec, (_, _, s, e, stats) in zip(recs, spans):
         assert stats == {"cached": int(rec.cached)}
         assert (e - s) / 1e9 == pytest.approx(rec.wall_s, abs=5e-3)
+
+
+def test_oracle_span_counts_its_contractions_by_path(tmp_path):
+    """``func:pallas`` with ``check=True`` sets the ``repro.ref.oracle``
+    span's counter: every contraction of the numpy oracle, all on the
+    exact float64 path."""
+    art = flow.compile("tiny_cnn", default_chip(), flow.CompileOptions(
+        strategy="dp", batch=2, workload_kw={"res": 8},
+        fidelity="analytic"))
+    spans = _trace(tmp_path, lambda: art.evaluate("func:pallas"))
+    oracle = [stats for _, n, _, _, stats in spans
+              if n == "repro.ref.oracle"]
+    n_mvm = sum(g.anchor is not None for g in art.cg)
+    assert oracle == [{"f64": 2 * n_mvm}]
