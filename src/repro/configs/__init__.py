@@ -9,10 +9,10 @@ from .base import (ArchConfig, MlaConfig, MoeConfig, ShapeConfig,
 
 from . import (deepseek_coder_33b, deepseek_v3_671b, h2o_danube3_4b,
                jamba15_large_398b, llava_next_mistral_7b, mamba2_780m,
-               olmoe_1b_7b, phi3_medium_14b, phi4_mini_3_8b,
+               olmoe_1b_7b, ouro_2_6b, phi3_medium_14b, phi4_mini_3_8b,
                whisper_small)
 
-__all__ = ["ARCHS", "get_arch", "valid_cells", "cell_skip_reason",
+__all__ = ["ARCHS", "CIM_ARCHS", "get_arch", "cim_arch", "valid_cells", "cell_skip_reason",
            "ArchConfig", "ShapeConfig", "STANDARD_SHAPES", "reduced",
            "MoeConfig", "MlaConfig", "SsmConfig"]
 
@@ -24,6 +24,12 @@ _MODULES = [
 
 ARCHS: Dict[str, ArchConfig] = {m.ARCH.name: m.ARCH for m in _MODULES}
 
+# architectures only the CIM compiler's normal path lowers
+# (``core.workloads.build("arch", arch=...)``): the LM substrate's blocks
+# have no weight-shared loop steps or sandwich norms
+CIM_ARCHS: Dict[str, ArchConfig] = {m.ARCH.name: m.ARCH
+                                    for m in (ouro_2_6b,)}
+
 
 def get_arch(name: str) -> ArchConfig:
     try:
@@ -31,6 +37,18 @@ def get_arch(name: str) -> ArchConfig:
     except KeyError:
         raise KeyError(f"unknown arch {name!r}; have {sorted(ARCHS)}") \
             from None
+
+
+def cim_arch(name: str) -> ArchConfig:
+    """An architecture the CIM compiler can lower: any of
+    :data:`ARCHS` or :data:`CIM_ARCHS`."""
+    if name in CIM_ARCHS:
+        return CIM_ARCHS[name]
+    try:
+        return ARCHS[name]
+    except KeyError:
+        raise KeyError(f"unknown arch {name!r}; have "
+                       f"{sorted(ARCHS) + sorted(CIM_ARCHS)}") from None
 
 
 def cell_skip_reason(cfg: ArchConfig, shape: ShapeConfig) -> str:

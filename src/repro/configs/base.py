@@ -79,6 +79,18 @@ class ArchConfig:
     # vision-language (llava): patch embeddings prepended to text
     vision_tokens: int = 0
     mtp: bool = False                   # multi-token-prediction head (dsv3)
+    # looped LMs (LoopLM / Ouro): the whole stack runs ``loop_steps``
+    # times per forward with the same weights; the final norm is applied
+    # after every step and its output feeds the next one
+    loop_steps: int = 1
+    # where the RMSNorms sit in a block: ``pre`` (h + f(N(h))) or
+    # ``sandwich`` (h + N'(f(N(h))), a second norm on each sublayer's
+    # output before its residual add)
+    norm_placement: str = "pre"
+    # per-step early-exit gate (a d_model -> 1 linear and a sigmoid on
+    # the step's final-normed state); a threshold of 1 never exits early
+    exit_gate: bool = False
+    early_exit_threshold: float = 1.0
     # numerics
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
@@ -87,6 +99,11 @@ class ArchConfig:
     # -- derived ---------------------------------------------------------------
 
     def __post_init__(self):
+        if self.norm_placement not in ("pre", "sandwich"):
+            raise ValueError(f"{self.name}: norm_placement must be 'pre' "
+                             f"or 'sandwich', got {self.norm_placement!r}")
+        if self.loop_steps < 1:
+            raise ValueError(f"{self.name}: loop_steps must be >= 1")
         if self.n_layers % len(self.block_pattern):
             raise ValueError(
                 f"{self.name}: {self.n_layers} layers not divisible by "

@@ -48,9 +48,19 @@ Limitations (documented): ``avgpool`` as a fused op is not generated
 (none of the paper's benchmarks use it outside GAP); *static*
 multi-round weight streaming requires single-chunk groups (true for the
 oversized FC layers that trigger it; the dynamic path re-loads per
-chunk instead); other non-affine activations (silu/sigmoid/...)
-execute on the vector unit's LUT path — timing is modeled, functional
-simulation rejects them.
+chunk instead).
+
+Ordered int8 tails (:func:`ordered_tail`: linear groups with RMSNorm,
+RoPE, a gated product, a tap or a bias-free LUT activation) apply their
+fused ops one by one in graph order: RMSNorm as ``V_LAYERNORM`` with its
+centring off (flag ``rms``) and a broadcast ``V_MUL`` by its int8 gain,
+RoPE as one ``V_ROPE`` per head over a Q14 table in local memory, the
+gated product as ``V_SILU`` on the gate, ``V_ZERO``/``V_MAC`` into
+int32 and ``V_QUANT``.  A tap (the stream before the group's final
+norm, read by a later residual add) is copied aside with ``V_MOV`` and
+delivered on its own.  Loop-shared groups use their owner's gmem
+weight blobs.  On the legacy tail, a LUT activation after a conv or a
+bias (efficientnetb0) is still not emitted.
 """
 
 from __future__ import annotations
@@ -62,6 +72,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from . import vecsem
 from .arch import ChipConfig
 from .graph import CondensedGraph, Group
 from .isa import FLAGS, Instr, Isa, Program, SREG, VFUNCT, default_isa
@@ -82,16 +93,23 @@ class CodegenError(ValueError):
 
 @dataclass(frozen=True)
 class QuantParams:
-    """Fixed-point requant: out = clip(rnd(acc*scale / (div*2^shift)), i8)."""
+    """Fixed-point requant: out = clip(rnd(acc*scale / (div*2^shift)), i8).
+
+    ``mul_shift`` requantizes a fused int8 product (``glu``: the int32
+    products of two int8 operands, rounded half up by ``2**mul_shift``).
+    """
 
     scale: int = 1
     shift: int = 8
+    mul_shift: int = 0
 
     def __post_init__(self):
         if not 0 < self.scale < (1 << 15):
             raise CodegenError(f"q-scale {self.scale} out of imm16 range")
-        if not 0 <= self.shift < 31:
-            raise CodegenError(f"q-shift {self.shift} out of range")
+        for name in ("shift", "mul_shift"):
+            if not 0 <= getattr(self, name) < 31:
+                raise CodegenError(f"q-{name} {getattr(self, name)} out "
+                                   f"of range")
 
 
 @dataclass
@@ -103,6 +121,15 @@ class GmemLayout:
     biases: Dict[int, Tuple[int, int]] = field(default_factory=dict)
     acts: Dict[Tuple[int, int], Tuple[int, int]] = \
         field(default_factory=dict)      # (gid, sample) -> (addr, nbytes)
+    # ordered-tail groups: the stream tapped before the group's final
+    # output, where a later stage reads it: (gid, sample) -> (addr, nb)
+    taps: Dict[Tuple[int, int], Tuple[int, int]] = \
+        field(default_factory=dict)
+    # RMSNorm gains (int8, keyed by Op.param_owner) and RoPE tables
+    # (int16 Q14, keyed by (rows, head_dim, theta))
+    norms: Dict[int, Tuple[int, int]] = field(default_factory=dict)
+    rope: Dict[Tuple[int, int, float], Tuple[int, int]] = \
+        field(default_factory=dict)
     inputs: Dict[int, Tuple[int, int]] = field(default_factory=dict)
     # graph-input op id -> byte offset within each per-sample input
     # region (multi-input graphs, e.g. decode's token + KV caches)
@@ -141,17 +168,42 @@ class CompiledModel:
     def total_instrs(self) -> int:
         return sum(s.total_instrs for s in self.stages)
 
+    @property
+    def weight_bytes(self) -> int:
+        """Unique weight bytes in gmem (loop-shared weights once)."""
+        return sum(nb for _, nb in self.layout.weights.values())
+
+    @property
+    def weight_load_bytes(self) -> int:
+        """Weight bytes CIM_LOADed per sample: every stage's prologue
+        loads over the batch, plus multi-round streaming every sample
+        (dynamic weights are activations and not counted)."""
+        total = 0
+        for st in self.stages:
+            for sc in st.schedules:
+                if sc.weight_source == "dynamic":
+                    continue
+                nb = sum(a.k_len * a.n_len for rep in sc.replicas
+                         for a in rep.assigns)
+                total += nb * (self.batch if sc.n_rounds > 1 else 1)
+        return total // max(self.batch, 1)
+
     # -- functional-mode gmem image -------------------------------------------
 
     def build_gmem_image(self, weights: Dict[int, np.ndarray],
                          biases: Optional[Dict[int, np.ndarray]],
-                         inputs: np.ndarray) -> np.ndarray:
+                         inputs: np.ndarray,
+                         norms: Optional[Dict[int, np.ndarray]] = None
+                         ) -> np.ndarray:
         """Materialize weight/bias/input blobs into a gmem byte image.
 
         ``weights[gid]``: int8 ``(K_total, N_total)`` matrix in the group's
         im2col layout (for depth-wise groups this is the block-diagonal
-        expansion; tile blobs are dense slices of it).
+        expansion; tile blobs are dense slices of it), keyed by
+        ``Group.param_key``: loop-shared groups share one copy.
         ``inputs``: int8 ``(batch, H, W, C)`` (or ``(batch, K)``).
+        ``norms``: int8 RMSNorm gains by ``Op.param_owner`` (default
+        ones).
         """
         biases = biases or {}
         img = np.zeros(self.layout.size, dtype=np.int8)
@@ -173,6 +225,14 @@ class CompiledModel:
             b = np.ascontiguousarray(biases[gid], dtype=np.int32)
             assert b.nbytes == nb
             put(addr, b.view(np.int8).reshape(-1))
+        for key, (addr, nb) in self.layout.norms.items():
+            gain = (np.ones(nb, dtype=np.int8) if norms is None
+                    else np.asarray(norms[key], dtype=np.int8))
+            assert gain.size == nb
+            put(addr, gain)
+        for (rows, hd, theta), (addr, nb) in self.layout.rope.items():
+            put(addr, vecsem.rope_table(rows, hd, theta)
+                .view(np.int8).reshape(-1))
         for s, (addr, nb) in self.layout.inputs.items():
             put(addr, np.ascontiguousarray(
                 inputs[s], dtype=np.int8).reshape(-1))
@@ -181,7 +241,7 @@ class CompiledModel:
     def _assign(self, gid, k_off, n_off, ch_off) -> MgAssign:
         for st in self.stages:
             for sc in st.schedules:
-                if sc.gid != gid:
+                if self.cg[sc.gid].param_key != gid:
                     continue
                 for a in sc.replicas[0].assigns:
                     if (a.k_off, a.n_off, a.ch_off) == (k_off, n_off,
@@ -458,6 +518,33 @@ def _side_pre_reduce(sched: OpSchedule) -> bool:
 SPECIAL_TAIL_OPS = ("softmax", "layernorm", "gelu")
 
 
+def causal_softmax(cg: CondensedGraph, g: Group) -> bool:
+    """Is the group's fused softmax causal (``attrs['causal']``: a
+    decoder's attention, rows are query positions)?"""
+    return cg.source is not None and any(
+        cg.source.ops[i].kind == "softmax"
+        and cg.source.ops[i].attrs.get("causal") for i in g.op_ids)
+
+
+def softmax_calls(o0: int, o1: int, n: int, seg: int, causal: bool
+                  ) -> List[Tuple[str, int, int, int]]:
+    """The vector instructions of a softmax over the assembled int8
+    rows ``[o0, o1)`` of ``n`` elements in head segments of ``seg``:
+    ``(funct, byte offset, vlen, rep)``, each segment ``rep``-strided by
+    ``seg``.  Causal: row ``r`` (query position ``r``) normalizes the
+    first ``r + 1`` keys of each head and zeroes the rest
+    (:func:`vecsem.causal_softmax_i8`)."""
+    if not causal:
+        return [("softmax", 0, seg, (o1 - o0) * n // seg)]
+    out = []
+    for r in range(o0, o1):
+        base, vis = (r - o0) * n, min(r + 1, seg)
+        out.append(("softmax", base, vis, n // seg))
+        if vis < seg:
+            out.append(("zero", base + vis, seg - vis, n // seg))
+    return out
+
+
 def _validate_special_tail(sched: OpSchedule) -> None:
     """Codegen applies softmax/layernorm/gelu last, on the assembled
     int8 output rows — reject fusion orders that contract can't honor."""
@@ -482,6 +569,21 @@ def _validate_special_tail(sched: OpSchedule) -> None:
         raise CodegenError(
             f"{sched.name}: fused {trailing[0]!r} follows "
             f"{vo[min(sp)]} — unsupported fusion order")
+
+
+def _validate_ordered_tail(cg: CondensedGraph, g: Group) -> None:
+    """An ordered tail delivers its final output and at most one tap:
+    refuse a group with another op read outside it."""
+    member = set(g.op_ids)
+    for h in cg:
+        for i in h.op_ids:
+            for s in cg.source.ops[i].inputs:
+                if s in member and s not in (g.op_ids[-1], g.tap) \
+                        and h.idx != g.idx:
+                    raise CodegenError(
+                        f"{g.name}: {cg.source.ops[s].name} is read by "
+                        f"{h.name} but is neither the group's output nor "
+                        f"its tap")
 
 
 def _relu_after_side(sched: OpSchedule) -> bool:
@@ -518,6 +620,59 @@ def _weight_pred(cg: CondensedGraph, g: Group,
     return op_owner.get(anchor.inputs[1])
 
 
+# fused ops that only the ordered int8 tail lowers (see ordered_tail)
+ORDERED_TAIL_OPS = ("rmsnorm", "rope", "glu")
+LUT_ACTS = ("silu", "sigmoid")
+
+
+def ordered_tail(cg: CondensedGraph, g: Group) -> bool:
+    """Does this group take the ordered int8 tail?
+
+    The ordered tail applies a linear group's fused ops one by one in
+    graph order on its int8 output rows (norms, RoPE, gated products,
+    LUT activations, residual adds, softmax...), keeps a *tap* of the
+    stream where one is read outside, and runs input-side norms on the
+    main operand.  Every other group keeps the legacy tail (residual
+    side op, then pooling, then the softmax/layernorm/gelu specials),
+    whose statistics are pinned: a bias-carrying LUT activation (the
+    SE linears of efficientnetb0) stays there, unlowered, as before.
+    """
+    src = cg.source
+    if src is None or g.anchor is None \
+            or src.ops[g.anchor].kind in ("conv", "dwconv"):
+        return False
+    kinds = [src.ops[i].kind for i in g.op_ids]
+    if any(k in ("maxpool", "avgpool", "globalpool") for k in kinds):
+        return False
+    return (g.tap is not None or any(k in ORDERED_TAIL_OPS for k in kinds)
+            or any(src.ops[i].attrs.get("pre") for i in g.op_ids)
+            or (any(k in LUT_ACTS for k in kinds) and "bias" not in kinds))
+
+
+def pre_ops(cg: CondensedGraph, g: Group) -> List[int]:
+    """The group's input-side ops (``attrs['pre']``), in order."""
+    if cg.source is None:
+        return []
+    return [i for i in g.op_ids if cg.source.ops[i].attrs.get("pre")]
+
+
+def _main_operand_op(cg: CondensedGraph, g: Group) -> Optional[int]:
+    """Op id whose output is the anchor's main operand, looking through
+    the group's input-side ops."""
+    op = cg.source.ops[g.anchor].inputs[0]
+    while cg.source.ops[op].attrs.get("pre"):
+        op = cg.source.ops[op].inputs[0]
+    return op
+
+
+def reads_tap(cg: CondensedGraph, h: Group, gid: int) -> bool:
+    """Does group ``h`` read group ``gid``'s tap (and not its output)?"""
+    tap = cg[gid].tap
+    if tap is None or cg.source is None or not ordered_tail(cg, cg[gid]):
+        return False
+    return any(tap in cg.source.ops[i].inputs for i in h.op_ids)
+
+
 def _main_and_skip_preds(cg: CondensedGraph, g: Group,
                          op_owner: Dict[int, int]) -> Tuple[Optional[int],
                                                             List[int]]:
@@ -527,8 +682,7 @@ def _main_and_skip_preds(cg: CondensedGraph, g: Group,
     a residual — it is excluded here and routed by the weight path."""
     main: Optional[int] = None
     if g.anchor is not None and cg.source is not None:
-        src_op = cg.source.ops[g.anchor].inputs[0]
-        main = op_owner.get(src_op)      # None => graph input
+        main = op_owner.get(_main_operand_op(cg, g))  # None => graph in
     elif g.preds:
         main = g.preds[0]
     wp = _weight_pred(cg, g, op_owner)
@@ -559,7 +713,8 @@ def _side_input_ops(cg: CondensedGraph, g: Group) -> List[int]:
                 continue
             if s == wop or s in out:
                 continue
-            if s == main_in and (g.anchor is None or i == g.anchor):
+            if s == main_in and (g.anchor is None or i == g.anchor
+                                 or cg.source.ops[i].attrs.get("pre")):
                 continue
             out.append(s)
     return out
@@ -571,7 +726,7 @@ def _main_input_op(cg: CondensedGraph, g: Group) -> Optional[int]:
         return None
     if g.anchor is not None:
         ins = cg.source.ops[g.anchor].inputs
-        return ins[0] if ins else None
+        return _main_operand_op(cg, g) if ins else None
     return next((s for i in g.op_ids for s in cg.source.ops[i].inputs
                  if cg.source.ops[s].kind == "input"), None)
 
@@ -618,7 +773,7 @@ def _compile_model(result: PartitionResult, batch: Optional[int] = None,
     _ensure_vec_flag_operand(isa)
     batch = batch if batch is not None else result.params.batch
     quant = quant or {}
-    qp = {g.idx: quant.get(g.idx, QuantParams()) for g in cg}
+    qp = {g.idx: quant.get(g.param_key, QuantParams()) for g in cg}
 
     layout = GmemLayout()
     in_bytes = _graph_input_bytes(cg)
@@ -673,15 +828,18 @@ def _compile_stage(cg: CondensedGraph, sp: StagePlan,
     # weights are activations, they never materialize in gmem) +
     # boundary activation buffers
     for sched in schedules:
+        pkey = cg[sched.gid].param_key     # loop-shared: one gmem copy
         if sched.weight_source != "dynamic":
             for a in sched.replicas[0].assigns:
-                key = (sched.gid, a.k_off, a.n_off, a.ch_off)
+                key = (pkey, a.k_off, a.n_off, a.ch_off)
                 if key not in layout.weights:
                     nb = a.k_len * a.n_len
                     layout.weights[key] = (layout.alloc(nb), nb)
-        if "bias" in sched.vector_ops and sched.gid not in layout.biases:
+        if "bias" in sched.vector_ops and pkey not in layout.biases:
             nb = sched.n_total * 4
-            layout.biases[sched.gid] = (layout.alloc(nb), nb)
+            layout.biases[pkey] = (layout.alloc(nb), nb)
+        if ordered_tail(cg, cg[sched.gid]):
+            _alloc_tail_consts(cg, cg[sched.gid], sched, layout)
         if sched.n_rounds > 1 and sched.n_chunks > 1 \
                 and sched.weight_source != "dynamic":
             # the dynamic path re-loads weights per (chunk, round) from
@@ -690,18 +848,20 @@ def _compile_stage(cg: CondensedGraph, sp: StagePlan,
             raise CodegenError(
                 f"{sched.name}: multi-round weight streaming requires a "
                 f"single m-chunk (got {sched.n_chunks})")
-        _validate_special_tail(sched)
+        if ordered_tail(cg, cg[sched.gid]):
+            _validate_ordered_tail(cg, cg[sched.gid])
+        else:
+            _validate_special_tail(sched)
     for sched in schedules:
         g = cg[sched.gid]
-        consumers = [h for h in cg if g.idx in h.preds]
-        boundary_out = (not consumers) or any(h.idx not in member
-                                              for h in consumers) \
-            or g.idx in force_boundary
-        if boundary_out:
-            _, _, total = _out_geometry(cg, sched)
-            for s in range(batch):
-                if (g.idx, s) not in layout.acts:
-                    layout.acts[(g.idx, s)] = (layout.alloc(total), total)
+        final_out, tap_out = _boundary_outputs(cg, g, member,
+                                               force_boundary)
+        _, _, total = _out_geometry(cg, sched)
+        for s in range(batch):
+            if final_out and (g.idx, s) not in layout.acts:
+                layout.acts[(g.idx, s)] = (layout.alloc(total), total)
+            if tap_out and (g.idx, s) not in layout.taps:
+                layout.taps[(g.idx, s)] = (layout.alloc(total), total)
 
     emitters: Dict[int, _Emitter] = {}
     lmems: Dict[int, _Lmem] = {}
@@ -724,13 +884,16 @@ def _compile_stage(cg: CondensedGraph, sp: StagePlan,
 
     # 1. weight prologue (round 0; later rounds stream inside the loop).
     # Dynamic groups have no prologue — their weights are per-sample
-    # activations — but any static bias blob still loads here.
+    # activations — but any static bias blob still loads here, as do an
+    # ordered tail's norm gains and RoPE tables.
     for sched in schedules:
         for rep in sched.replicas:
+            if ordered_tail(cg, cg[sched.gid]):
+                _emit_tail_consts(ctx, sched, rep)
             if sched.weight_source == "dynamic":
                 if "bias" in sched.vector_ops \
-                        and sched.gid in layout.biases:
-                    addr, nb = layout.biases[sched.gid]
+                        and cg[sched.gid].param_key in layout.biases:
+                    addr, nb = layout.biases[cg[sched.gid].param_key]
                     bb = bufs[(sched.gid, rep.replica)]
                     for c in rep.cores:
                         em(c).gld(bb["bias"][c], addr, nb)
@@ -795,6 +958,66 @@ def _validate_channels(emitters: Dict[int, _Emitter]) -> None:
 def _stream_id(producer_gid: int, consumer_gid: int, kind: int) -> int:
     """Virtual-channel id: (producer, consumer, kind) -> unique tag."""
     return (producer_gid * 128 + consumer_gid) * 8 + kind
+
+
+def _boundary_outputs(cg: CondensedGraph, g: Group, member: Set[int],
+                      force_boundary: Set[int]) -> Tuple[bool, bool]:
+    """Whether the group's final output, and its tap, go to gmem: a
+    reader in a later stage, or (final) no reader at all."""
+    consumers = [h for h in cg if g.idx in h.preds]
+    tapped = [h for h in consumers if reads_tap(cg, h, g.idx)]
+    final = [h for h in consumers if h not in tapped]
+    final_out = (not consumers) or any(h.idx not in member for h in final) \
+        or g.idx in force_boundary
+    return final_out, any(h.idx not in member for h in tapped)
+
+
+def _tail_ops(cg: CondensedGraph, g: Group) -> List[int]:
+    """Ordered-tail op ids after the requantization, in graph order."""
+    src = cg.source
+    ids = [i for i in g.op_ids if i != g.anchor
+           and not src.ops[i].attrs.get("pre")
+           and src.ops[i].kind not in ("bn", "flatten", "identity")]
+    kinds = [src.ops[i].kind for i in ids]
+    k = 1 if kinds[:1] == ["bias"] else 0        # applied on INT32
+    return ids[k + 1:] if kinds[k:k + 1] == ["relu"] else ids[k:]
+
+
+def _alloc_tail_consts(cg: CondensedGraph, g: Group, sched: OpSchedule,
+                       layout: GmemLayout) -> None:
+    """gmem blobs of an ordered tail: each RMSNorm gain once per owner,
+    one RoPE table per (rows, head dim, theta)."""
+    for i in g.op_ids:
+        op = cg.source.ops[i]
+        if op.kind == "rmsnorm" and op.param_owner not in layout.norms:
+            nb = op.out_shape[-1]
+            layout.norms[op.param_owner] = (layout.alloc(nb), nb)
+        elif op.kind == "rope":
+            key = (sched.m_total, op.attrs["head_dim"], op.attrs["theta"])
+            if key not in layout.rope:
+                nb = sched.m_total * op.attrs["head_dim"] * 2
+                layout.rope[key] = (layout.alloc(nb), nb)
+
+
+def _emit_tail_consts(ctx: "_Ctx", sched: OpSchedule,
+                      rep: ReplicaPlan) -> None:
+    """Stage prologue of an ordered tail: GLD its norm gains (input-side
+    ones to every core of the replica, tail ones to the assembly core)
+    and its rows of the RoPE table."""
+    b = ctx.bufs[(sched.gid, rep.replica)]
+    o0, o1 = _owned_out_rows(ctx.cg, sched, rep)
+    for i, buf in b["gain"].items():
+        op = ctx.cg.source.ops[i]
+        addr, nb = ctx.layout.norms[op.param_owner]
+        cores = rep.cores if op.attrs.get("pre") else rep.cores[:1]
+        for c in cores:
+            ctx.em(c).gld(buf[c], addr, nb)
+    for i, buf in b["rope"].items():
+        op = ctx.cg.source.ops[i]
+        hd = op.attrs["head_dim"]
+        addr, _ = ctx.layout.rope[(sched.m_total, hd, op.attrs["theta"])]
+        ctx.em(rep.cores[0]).gld(buf, addr + o0 * hd * 2,
+                                 (o1 - o0) * hd * 2)
 
 
 @dataclass
@@ -882,7 +1105,34 @@ def _plan_buffers(cg: CondensedGraph, sched: OpSchedule, rep: ReplicaPlan,
         out["skip"] = lmems[asm].alloc(
             0, max(max(k1 - k0, 1) * krow_nb, (o1 - o0) * row_nb),
             f"{tag} skip")
+    if ordered_tail(cg, g):
+        _plan_tail_buffers(cg, g, sched, rep, lmems, out, o1 - o0, tag)
     return out
+
+
+def _plan_tail_buffers(cg: CondensedGraph, g: Group, sched: OpSchedule,
+                       rep: ReplicaPlan, lmems, out: Dict, rows: int,
+                       tag: str) -> None:
+    """An ordered tail's buffers: norm gains (input-side: per core),
+    RoPE table rows, the int32 gated-product accumulator and the tap."""
+    asm = rep.cores[0]
+    src = cg.source
+    out["gain"], out["rope"] = {}, {}
+    N = sched.n_total
+    for i in g.op_ids:
+        op = src.ops[i]
+        if op.kind == "rmsnorm":
+            cores = rep.cores if op.attrs.get("pre") else (asm,)
+            out["gain"][i] = {c: lmems[c].alloc(2, op.out_shape[-1],
+                                                f"{tag} gain")
+                              for c in cores}
+        elif op.kind == "rope":
+            out["rope"][i] = lmems[asm].alloc(
+                3, rows * op.attrs["head_dim"] * 2, f"{tag} rope")
+        elif op.kind == "glu":
+            out["glu"] = lmems[asm].alloc(2, rows * N * 4, f"{tag} glu")
+    if g.tap is not None:
+        out["tap"] = lmems[asm].alloc(3, rows * N, f"{tag} tap")
 
 
 def _round_mask(rep: ReplicaPlan, core: int, rnd: int) -> int:
@@ -939,8 +1189,8 @@ def _emit_weight_load(ctx: _Ctx, sched: OpSchedule, rep: ReplicaPlan,
         if dynamic:
             _emit_weight_gather(ctx, sched, b, e, a)
         else:
-            addr, nb = ctx.layout.weights[(sched.gid, a.k_off, a.n_off,
-                                           a.ch_off)]
+            addr, nb = ctx.layout.weights[(ctx.cg[sched.gid].param_key,
+                                           a.k_off, a.n_off, a.ch_off)]
             e.gld(b["wstage"][a.core], addr, nb)
         e.sreg("MG_SEL", a.slot)
         e.sreg("MG_KOFF", a.k_off)
@@ -951,9 +1201,10 @@ def _emit_weight_load(ctx: _Ctx, sched: OpSchedule, rep: ReplicaPlan,
     # static bias rides round 0 of the (re)load; dynamic groups load it
     # once in the stage prologue instead (their weights re-load every
     # sample, the bias blob does not change)
+    pkey = ctx.cg[sched.gid].param_key
     if rnd == 0 and not dynamic and "bias" in sched.vector_ops \
-            and sched.gid in ctx.layout.biases:
-        addr, nb = ctx.layout.biases[sched.gid]
+            and pkey in ctx.layout.biases:
+        addr, nb = ctx.layout.biases[pkey]
         for c in rep.cores:
             ctx.em(c).gld(b["bias"][c], addr, nb)
 
@@ -1057,9 +1308,10 @@ def _emit_sample(ctx: _Ctx, sched: OpSchedule, rep: ReplicaPlan,
     r0, r1 = _needed_in_rows(cg, sched, rep, in_rows_total)
     row_nb = _in_row_bytes(sched)
     need_lo, need_hi = r0 * row_nb, r1 * row_nb
+    ordered = ordered_tail(cg, g)
     if main is None or main not in ctx.member:
         base, _ = (ctx.layout.inputs[s] if main is None
-                   else ctx.layout.acts[(main, s)])
+                   else _gmem_src(ctx, g, main, s))
         if main is None:
             # multi-input graphs: offset to this group's input operand
             # within the per-sample region (0 for single-input graphs)
@@ -1080,6 +1332,12 @@ def _emit_sample(ctx: _Ctx, sched: OpSchedule, rep: ReplicaPlan,
                                prep.cores[0], hi - lo,
                                tag=f"in:{g.name}@s{s}",
                                stream=_stream_id(main, g.idx, 0))
+
+    # input-side norms (ordered tails): every core normalizes its rows
+    for i in pre_ops(cg, g):
+        for c in rep.cores:
+            _emit_norm(ctx.em(c), b["in"][c], b["gain"][i][c],
+                       r1 - r0, sched.k_total)
 
     # ---- 1b. acquire skip/scale operands --------------------------------------
     o0, o1 = _owned_out_rows(cg, sched, rep)
@@ -1112,7 +1370,7 @@ def _emit_sample(ctx: _Ctx, sched: OpSchedule, rep: ReplicaPlan,
                     tag=f"skip:{g.name}@s{s}",
                     stream=_stream_id(sgid, g.idx, 2 if bcast else 1))
         else:
-            base, nbt = ctx.layout.acts[(sgid, s)]
+            base, nbt = _gmem_src(ctx, g, sgid, s)
             if bcast:
                 ctx.em(rep.cores[0]).gld(b["skip"], base, nbt)
             else:
@@ -1212,7 +1470,13 @@ def _emit_sample(ctx: _Ctx, sched: OpSchedule, rep: ReplicaPlan,
     e = ctx.em(rep.cores[0])
 
     # ---- 4. fused tail (graph order) ------------------------------------------
-    has_side_op = "add" in sched.vector_ops or "mul" in sched.vector_ops
+    if ordered:
+        if len(side) + len(side_inputs) > 1:
+            raise CodegenError(f"{g.name}: an ordered tail takes one "
+                               f"side operand")
+        _emit_ordered_tail(ctx, sched, b, e, q, o0, o1)
+    has_side_op = not ordered and (
+        "add" in sched.vector_ops or "mul" in sched.vector_ops)
     self_skip = has_side_op and not side and not side_inputs
     side_pre = _side_pre_reduce(sched)
 
@@ -1263,29 +1527,33 @@ def _emit_sample(ctx: _Ctx, sched: OpSchedule, rep: ReplicaPlan,
     # residual (validated by _validate_special_tail); row-segment
     # integer semantics shared with the oracle via repro.core.vecsem
     for vop in sched.vector_ops:
-        if vop not in SPECIAL_TAIL_OPS or o1 <= o0:
+        if ordered or vop not in SPECIAL_TAIL_OPS or o1 <= o0:
             continue
         total = (o1 - o0) * out_row_nb
         if vop == "gelu":
             e.vec("gelu", b["final"], b["final"], 0, vlen=total,
                   flags=FLAGS["i8"])
             continue
-        # softmax normalizes per head-row segment; layernorm per row
-        seg = g.gemm_n if vop == "softmax" else sched.n_total
+        if vop == "softmax":
+            # per head-row segment (causal: per query row's prefix)
+            _emit_softmax(e, b["final"], o0, o1, out_row_nb, g.gemm_n,
+                          causal_softmax(cg, g))
+            continue
+        seg = sched.n_total                  # layernorm: per row
         e.vec(vop, b["final"], b["final"], 0, vlen=seg,
               rep=total // seg, seg_d=seg, seg_a=seg, flags=FLAGS["i8"])
 
     # ---- 5. deliver -------------------------------------------------------------
     consumers = [h for h in cg if g.idx in h.preds]
-    boundary_out = (not consumers) or any(h.idx not in ctx.member
-                                          for h in consumers) \
-        or g.idx in ctx.force_boundary
+    boundary_out, tap_out = _boundary_outputs(cg, g, ctx.member,
+                                              ctx.force_boundary)
     my_rows, my_row_nb, _ = _out_geometry(cg, sched)
     for h in consumers:
         if h.idx not in ctx.member:
             continue
         cons = ctx.by_gid[h.idx]
         hmain, _ = _main_and_skip_preds(cg, h, ctx.op_owner)
+        out_buf = b["tap"] if reads_tap(cg, h, g.idx) else b["final"]
         if cons.weight_source == "dynamic" and cons.weight_pred == g.idx:
             # this output IS the consumer's weight operand: every core
             # of every consumer replica gathers tiles from it
@@ -1309,7 +1577,7 @@ def _emit_sample(ctx: _Ctx, sched: OpSchedule, rep: ReplicaPlan,
                 if hi_b <= lo_b:
                     continue
                 for tc in crep.cores:
-                    e.send(tc, b["final"] + lo_b - o0 * my_row_nb,
+                    e.send(tc, out_buf + lo_b - o0 * my_row_nb,
                            hi_b - lo_b,
                            tag=f"out:{g.name}->{h.name}@s{s}",
                            stream=_stream_id(g.idx, h.idx, 0))
@@ -1325,13 +1593,92 @@ def _emit_sample(ctx: _Ctx, sched: OpSchedule, rep: ReplicaPlan,
             lo, hi = max(o0, c0), min(o1, c1)
             if hi <= lo:
                 continue
-            e.send(crep.cores[0], b["final"] + (lo - o0) * out_row_nb,
+            e.send(crep.cores[0], out_buf + (lo - o0) * out_row_nb,
                    (hi - lo) * out_row_nb,
                    tag=f"side:{g.name}->{h.name}@s{s}",
                    stream=_stream_id(g.idx, h.idx, 1))
     if boundary_out and o1 > o0:
         base, _ = ctx.layout.acts[(g.idx, s)]
         e.gst(b["final"], base + o0 * out_row_nb, (o1 - o0) * out_row_nb)
+    if tap_out and o1 > o0:
+        base, _ = ctx.layout.taps[(g.idx, s)]
+        e.gst(b["tap"], base + o0 * out_row_nb, (o1 - o0) * out_row_nb)
+
+
+def _gmem_src(ctx: _Ctx, g: Group, prod: int, s: int) -> Tuple[int, int]:
+    """gmem (addr, nbytes) of what group ``g`` reads from an earlier
+    stage's group ``prod``: its tap or its final output."""
+    if reads_tap(ctx.cg, g, prod):
+        return ctx.layout.taps[(prod, s)]
+    return ctx.layout.acts[(prod, s)]
+
+
+def _emit_norm(e: _Emitter, buf: int, gain: int, rows: int,
+               n: int) -> None:
+    """RMSNorm over ``rows`` int8 rows of ``n`` in place: the norm unit
+    with its centring off, then the saturating per-channel gain."""
+    e.vec("layernorm", buf, buf, 0, vlen=n, rep=rows, seg_d=n, seg_a=n,
+          flags=FLAGS["i8"] | FLAGS["rms"])
+    e.vec("mul", buf, buf, gain, vlen=n, rep=rows, seg_d=n, seg_a=n,
+          seg_b=0, flags=FLAGS["i8"])
+
+
+def _emit_softmax(e: _Emitter, buf: int, o0: int, o1: int, n: int,
+                  seg: int, causal: bool) -> None:
+    """Softmax in place over int8 rows ``[o0, o1)`` held from ``buf``
+    (:func:`softmax_calls`)."""
+    for fn, off, vlen, rep in softmax_calls(o0, o1, n, seg, causal):
+        e.vec(fn, buf + off, buf + off, 0, vlen=vlen, rep=rep, seg_d=seg,
+              seg_a=seg, flags=FLAGS["i8"])
+
+
+def _emit_ordered_tail(ctx: _Ctx, sched: OpSchedule, b: Dict,
+                       e: _Emitter, q: QuantParams, o0: int,
+                       o1: int) -> None:
+    """The ordered int8 tail on the assembled rows [o0, o1) (semantics:
+    ``ref._ordered_sample``, integer definitions in vecsem)."""
+    if o1 <= o0:
+        return
+    cg, g = ctx.cg, ctx.cg[sched.gid]
+    src = cg.source
+    N = sched.n_total
+    rows = o1 - o0
+    total = rows * N
+    buf = b["final"]
+    i8 = FLAGS["i8"]
+    for i in _tail_ops(cg, g):
+        kind = src.ops[i].kind
+        if kind == "add":
+            e.vec("add", buf, buf, b["skip"], vlen=total, flags=i8)
+        elif kind == "glu":
+            # silu(gate) x up: int8 products into int32, then V_QUANT
+            e.vec("silu", b["skip"], b["skip"], 0, vlen=total, flags=i8)
+            e.vec("zero", b["glu"], 0, 0, vlen=total)
+            e.vec("mac", b["glu"], buf, b["skip"], vlen=total, flags=i8)
+            e.sreg("Q_SCALE", 1)
+            e.sreg("Q_SHIFT", q.mul_shift)
+            e.sreg("ACC_DIV", 1)
+            e.vec("quant", buf, b["glu"], 0, vlen=total)
+        elif kind == "rmsnorm":
+            _emit_norm(e, buf, b["gain"][i][e.prog.core_id], rows, N)
+        elif kind == "rope":
+            hd = src.ops[i].attrs["head_dim"]
+            for h0 in range(0, N, hd):
+                e.vec("rope", buf + h0, buf + h0, b["rope"][i], vlen=hd,
+                      rep=rows, seg_d=N, seg_a=N, seg_b=hd * 2, flags=i8)
+        elif kind == "softmax":
+            _emit_softmax(e, buf, o0, o1, N, g.gemm_n,
+                          bool(src.ops[i].attrs.get("causal")))
+        elif kind == "layernorm":
+            e.vec(kind, buf, buf, 0, vlen=N, rep=rows, seg_d=N, seg_a=N,
+                  flags=i8)
+        elif kind in ("silu", "sigmoid", "gelu", "relu"):
+            e.vec(kind, buf, buf, 0, vlen=total, flags=i8)
+        else:
+            raise CodegenError(f"{g.name}: fused {kind!r} has no "
+                               f"ordered-tail lowering")
+        if i == g.tap:
+            e.vec("mov", b["tap"], buf, 0, vlen=total, flags=i8)
 
 
 # -- chunk emission -----------------------------------------------------------

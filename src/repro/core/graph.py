@@ -68,6 +68,9 @@ VECTOR_KINDS = {
     "globalpool": "alu", "quant": "mul", "dequant": "mul",
     "layernorm": "special", "rmsnorm": "special", "concat": "alu",
     "pad": "alu", "flatten": "alu", "identity": "alu",
+    # rotary positions (two products per element) and the gated
+    # product silu(gate) * up of a gated FFN
+    "rope": "mul", "glu": "mul",
 }
 
 
@@ -127,6 +130,13 @@ class Op:
     @property
     def flops(self) -> int:
         return 2 * self.macs
+
+    @property
+    def param_owner(self) -> int:
+        """Op whose parameters (weights, requantization, norm gain) this
+        op uses: ``attrs['owner']`` for a loop step reusing step 0's,
+        else itself."""
+        return self.attrs.get("owner", self.idx)
 
     def __repr__(self) -> str:
         if self.is_mvm:
@@ -310,10 +320,24 @@ class Group:
     # incremental re-gather of just the appended row instead of
     # re-staging the whole buffer.  Set from ``attrs['kv_append']``.
     weight_incremental: bool = False
+    # Loop-shared parameters (looped LMs): the group whose anchor's
+    # weights, requantization and norm gains this group reuses (set
+    # from the anchor's ``attrs['owner']``); ``None`` owns its own.
+    owner: Optional[int] = None
+    # A *tap*: the one fused op before the last whose output is also
+    # read outside the group (a pre-norm block's residual stream, whose
+    # norm is the group's final output); ``None`` where there are none,
+    # or several (which only the legacy tail tolerates).
+    tap: Optional[int] = None
 
     @property
     def is_mvm(self) -> bool:
         return self.anchor is not None
+
+    @property
+    def param_key(self) -> int:
+        """Key of this group's weights, quantization and gmem blobs."""
+        return self.idx if self.owner is None else self.owner
 
     @property
     def dynamic_weights(self) -> bool:
@@ -371,6 +395,13 @@ class CondensedGraph:
         return sum(g.weight_bytes for g in self.groups)
 
     @property
+    def unique_weight_bytes(self) -> int:
+        """Stored weight bytes counted once per owner (loop-shared
+        weights); dynamic weights are activations and not counted."""
+        return sum(g.weight_bytes for g in self.groups
+                   if g.owner is None and not g.dynamic_weights)
+
+    @property
     def total_macs(self) -> int:
         return sum(g.macs for g in self.groups)
 
@@ -388,21 +419,31 @@ class CondensedGraph:
         Pass 1: assign every op to a group id — an MVM op starts a new group;
         a non-MVM op joins the group of its *latest* producer (adjacent
         grouping).  Ops preceding any MVM (stem pools etc.) join group of
-        their producer or a fresh anchor-less group for graph inputs.
+        their producer or a fresh anchor-less group for graph inputs.  An
+        op marked ``attrs['pre']`` instead joins the group of the MVM that
+        reads it as its main operand (an input-side norm, applied before
+        the anchor).
         Pass 2: renumber groups in topological order of first-op, collect
         geometry + boundary traffic.
         """
         n = len(g.ops)
         owner = [-1] * n
         groups_ops: List[List[int]] = []
+        _PRE = -2                           # waits for its MVM consumer
 
         for op in g.ops:
             if op.kind == "input":
                 owner[op.idx] = -1          # inputs belong to no group
                 continue
+            if op.attrs.get("pre"):
+                owner[op.idx] = _PRE
+                continue
             if op.is_mvm:
-                owner[op.idx] = len(groups_ops)
-                groups_ops.append([op.idx])
+                gid = len(groups_ops)
+                pre = [i for i in op.inputs[:1] if owner[i] == _PRE]
+                for i in pre + [op.idx]:
+                    owner[i] = gid
+                groups_ops.append(pre + [op.idx])
                 continue
             # non-MVM: fuse into the latest producing group
             prod_groups = [owner[i] for i in op.inputs if owner[i] >= 0]
@@ -414,6 +455,10 @@ class CondensedGraph:
             owner[op.idx] = gid
             groups_ops[gid].append(op.idx)
 
+        if _PRE in owner:
+            raise GraphError(
+                f"{g.ops[owner.index(_PRE)].name}: an input-side ('pre') "
+                f"op must be the main operand of an MVM")
         cons = g.consumers()
         # renumber non-empty groups in first-op order (already topological)
         renum = {gid: k for k, gid in enumerate(
@@ -446,10 +491,21 @@ class CondensedGraph:
                 if op.is_mvm:
                     continue
                 cls = _vec_class(op.kind)
-                vw[cls] = vw.get(cls, 0) + op.out_elems
+                n = op.out_elems
+                if op.attrs.get("causal"):
+                    # query row r normalizes keys 0..r, zeroes the rest
+                    q, k = op.out_shape[-2:]
+                    n = n // (q * k) * sum(min(r + 1, k) for r in range(q))
+                    vw["alu"] = vw.get("alu", 0) + op.out_elems - n
+                vw[cls] = vw.get(cls, 0) + n
             a = g.ops[anchor] if anchor is not None else None
+            taps = [i for i in op_ids[:-1] if i != anchor
+                    and not g.ops[i].attrs.get("pre")
+                    and any(c not in member for c in cons[i])]
+            first = next(i for i in op_ids if not g.ops[i].attrs.get("pre"))
+            shared = a.attrs.get("owner") if a is not None else None
             out.append(Group(
-                idx=renum[gid], name=g.ops[op_ids[0]].name,
+                idx=renum[gid], name=g.ops[first].name,
                 op_ids=tuple(op_ids), anchor=anchor,
                 preds=tuple(sorted(preds)),
                 gemm_m=a.gemm_m if a else 0, gemm_k=a.gemm_k if a else 0,
@@ -466,7 +522,9 @@ class CondensedGraph:
                 transpose_weights=bool(
                     a.attrs.get("transpose_weights")) if a else False,
                 weight_incremental=bool(
-                    a.attrs.get("kv_append")) if a else False))
+                    a.attrs.get("kv_append")) if a else False,
+                owner=renum[owner[shared]] if shared is not None else None,
+                tap=taps[0] if len(taps) == 1 else None))
         return CondensedGraph(g.name, out, source=g)
 
 

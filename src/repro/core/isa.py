@@ -407,7 +407,10 @@ VFUNCT: Dict[str, int] = {
     # row-segment transformer ops: VLEN = segment length, V_REP = rows
     # (int8 in/out; integer semantics in repro.core.vecsem)
     "softmax": 29,
-    "layernorm": 30,
+    "layernorm": 30,    # flag ``rms``: no mean removal (RMSNorm)
+    # rotary positions per head segment: VLEN = head dim, V_REP = rows,
+    # b = the rows' int16 Q14 cos/sin table (VSEG_B bytes per row)
+    "rope": 31,
 }
 
 # Scalar ALU functs (shared S_ALU opcode).
@@ -456,6 +459,7 @@ FLAGS: Dict[str, int] = {
     "acc": 1 << 0,      # CIM_MVM: accumulate into dst instead of overwrite
     "relu": 1 << 1,     # fused relu on vector op result
     "i8": 1 << 2,       # operate on int8 data (default int32)
+    "rms": 1 << 3,      # V_LAYERNORM: skip the mean removal (RMSNorm)
 }
 
 

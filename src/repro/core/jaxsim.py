@@ -46,11 +46,12 @@ back to the scalar interpreter per stage, unchanged.
 
 from __future__ import annotations
 
+import hashlib
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..obs import span
+from ..obs import recording, span
 from .isa import Isa, Program
 from .machine import MachineModel
 from .vectorsim import (
@@ -485,6 +486,24 @@ class FleetStageDecoder:
         with span("repro.sim.decode_prep", machines=len(self.machines)):
             self.npdecs = [StageDecoder(isa, m) for m in self.machines]
         self.tables = MachineTables.stack(self.machines)
+        self._seen: set = set()     # tables sent while recording
+
+    def _mark_repeat(self, cols: Dict[str, np.ndarray]) -> None:
+        """While a profiler records: mark, with an instant
+        ``repro.sim.device_call.repeat`` span before its call, a stage
+        table (digest of every column) this decoder already sent (a
+        loop step's stage decoded again)."""
+        if not recording():
+            return
+        h = hashlib.blake2b(digest_size=16)
+        for k in sorted(cols):
+            h.update(k.encode())
+            h.update(np.ascontiguousarray(cols[k]).tobytes())
+        fp = h.digest()
+        if fp in self._seen:
+            with span("repro.sim.device_call.repeat"):
+                pass
+        self._seen.add(fp)
 
     def prep(self, programs: Dict[int, Program]) -> _Prep:
         """Machine-independent front half (cacheable by the caller)."""
@@ -503,6 +522,7 @@ class FleetStageDecoder:
             with span("repro.sim.decode_prep", rows=pr.n, machines=n):
                 _, fleet_fn = _exec_for(self.npdecs[0], _reg_bucket(pr))
                 cols = _device_cols(pr)
+            self._mark_repeat(cols)
             lat, res = _call_exec(fleet_fn, self.tables.arrays, cols, pr.n)
         outs: List[_DecodedStage] = []
         for i, (m, dec) in enumerate(zip(self.machines, self.npdecs)):

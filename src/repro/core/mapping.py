@@ -60,9 +60,11 @@ def gmem_footprint_bytes(groups: "Iterable") -> int:
     partitioner uses this as the per-chip capacity rule — one chip's
     16 MB gmem is the wall that forces multi-chip plans.  The legacy
     single-chip path stays unguarded for backwards compatibility.
+    Loop-shared weights (``Group.param_key``) are one blob, counted once.
     """
-    return sum(g.weight_bytes for g in groups
-               if g.weight_source != "dynamic")
+    blobs = {g.param_key: g.weight_bytes for g in groups
+             if g.weight_source != "dynamic"}
+    return sum(blobs.values())
 
 
 @dataclass(frozen=True)

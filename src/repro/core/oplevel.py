@@ -179,7 +179,8 @@ def _conv_spec(cg: CondensedGraph, g: Group) -> Optional[Im2colSpec]:
 
 def _fused_vector_ops(cg: CondensedGraph, g: Group) \
         -> Tuple[Tuple[str, ...], Optional[PoolSpec], bool]:
-    """(post-anchor fused ops, pooling spec, gap?) — bn folds into requant."""
+    """(post-anchor fused ops, pooling spec, gap?) — bn folds into requant;
+    input-side (``pre``) ops are not post-ops."""
     if cg.source is None:
         return (), None, False
     out = []
@@ -187,7 +188,8 @@ def _fused_vector_ops(cg: CondensedGraph, g: Group) \
     gap = False
     for i in g.op_ids:
         op = cg.source.ops[i]
-        if op.is_mvm or op.kind in ("bn", "flatten", "identity"):
+        if op.is_mvm or op.kind in ("bn", "flatten", "identity") \
+                or op.attrs.get("pre"):
             continue
         if op.kind in ("maxpool", "avgpool"):
             ho, wo, _ = op.out_shape

@@ -16,7 +16,14 @@ generator + functional ISS contract:
   :func:`repro.core.vecsem.dynamic_weight_matrix` — the same layout
   codegen's gather V_MOVs realize;
 * fused ``softmax`` / ``layernorm`` / ``gelu`` through the shared
-  integer semantics in :mod:`repro.core.vecsem`.
+  integer semantics in :mod:`repro.core.vecsem`;
+* groups on codegen's ordered int8 tail (:func:`~repro.core.codegen.
+  ordered_tail`: RMSNorm with its per-channel gain, RoPE, the gated
+  product, SiLU/sigmoid) apply their fused ops in graph order, keep the
+  stream's *tap* where another group reads it, and run input-side norms
+  on the main operand;
+* loop-shared groups read the weights and quantization of their owner
+  (``Group.param_key``); an RMSNorm's gain γ is keyed by its owner op.
 
 Also provides the weight-matrix builders tests use to generate gmem
 images (`conv_weight_matrix`, `dwconv_weight_matrix`).
@@ -30,13 +37,15 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import numpy as np
 
 from . import vecsem
-from .codegen import QuantParams, _main_and_skip_preds, _weight_pred
+from .codegen import (QuantParams, _main_and_skip_preds, _main_operand_op,
+                      _tail_ops, _weight_pred, causal_softmax, ordered_tail,
+                      pre_ops)
 from .graph import CondensedGraph, Graph
 from .oplevel import Im2colSpec
 
 __all__ = ["conv_weight_matrix", "dwconv_weight_matrix", "im2col",
            "quantize", "run_reference", "auto_quant", "random_init",
-           "exact_matmul", "ContractionTally"]
+           "exact_matmul", "ContractionTally", "random_norms"]
 
 # the INT8 x INT8 -> INT32 accumulator contraction; swappable so the
 # same oracle can execute its MVMs on an accelerator kernel (see
@@ -144,13 +153,49 @@ def _group_spec(cg: CondensedGraph, g) -> Optional[Tuple]:
             op.kind == "dwconv")
 
 
+def random_norms(cg: CondensedGraph, seed: int = 0
+                 ) -> Dict[int, np.ndarray]:
+    """Random int8 RMSNorm gains in {1, 2}, one per owning op
+    (``Op.param_owner``)."""
+    rng = np.random.default_rng(seed)
+    keys = sorted({op.param_owner for op in cg.source.ops
+                   if op.kind == "rmsnorm"})
+    return {k: rng.integers(1, 3, cg.source.ops[k].out_shape[-1]
+                            ).astype(np.int8) for k in keys}
+
+
+def _gain(norms: Optional[Dict[int, np.ndarray]], op) -> np.ndarray:
+    if norms is None:
+        return np.ones(op.out_shape[-1], dtype=np.int8)
+    return norms[op.param_owner]
+
+
+def _apply_norm(x: np.ndarray, op, norms) -> np.ndarray:
+    """RMSNorm over each row, then the saturating int8 gain γ."""
+    n = op.out_shape[-1]
+    y = vecsem.rmsnorm_i8(x.reshape(-1, n))
+    return _sat_mul(y, _gain(norms, op)[None, :]).reshape(x.shape)
+
+
+def _rope(y: np.ndarray, op) -> np.ndarray:
+    """RoPE on every head of (rows, d) int8; row index = position."""
+    hd = op.attrs["head_dim"]
+    rows = y.shape[0]
+    tab = vecsem.rope_table(rows, hd, op.attrs["theta"])
+    out = np.empty_like(y)
+    for h0 in range(0, y.shape[1], hd):
+        out[:, h0:h0 + hd] = vecsem.rope_i8(y[:, h0:h0 + hd], tab)
+    return out
+
+
 def run_reference(cg: CondensedGraph, weights: Dict[int, np.ndarray],
                   biases: Dict[int, np.ndarray],
                   quant: Dict[int, QuantParams],
                   inputs: np.ndarray,
                   return_acc: bool = False,
                   matmul: Optional[MatmulFn] = None,
-                  faults: Optional[Any] = None
+                  faults: Optional[Any] = None,
+                  norms: Optional[Dict[int, np.ndarray]] = None
                   ) -> Dict[int, np.ndarray]:
     """Forward-pass every sample; returns {gid: (batch, ...) int8 maps}
     (conv groups: (B, ho', wo', N) post-fusion; vector groups: (B, N)).
@@ -167,6 +212,13 @@ def run_reference(cg: CondensedGraph, weights: Dict[int, np.ndarray],
     oracle bit-exactly unchanged.  Dynamic-weight (attention) matmuls
     build their matrix from activations at run time and carry no
     stored-weight faults.
+
+    ``weights``, ``biases`` and ``quant`` are keyed by
+    ``Group.param_key`` (a loop-shared group reads its owner's);
+    ``norms`` maps ``Op.param_owner`` to int8 RMSNorm gains (``None``:
+    all ones).  With ``return_acc`` the result also holds ``"acc"``
+    (per-group accumulators) and ``"glu"`` (per-group peak magnitude of
+    the gated products before their requantization).
     """
     mm: MatmulFn = matmul if matmul is not None else exact_matmul
     src = cg.source
@@ -178,12 +230,39 @@ def run_reference(cg: CondensedGraph, weights: Dict[int, np.ndarray],
     B = inputs.shape[0]
     outs: Dict[int, np.ndarray] = {}
     accs: Dict[int, np.ndarray] = {}
+    taps: Dict[int, np.ndarray] = {}
+    glu_peak: Dict[int, int] = {}
+
+    def operand(i: int, s: int) -> np.ndarray:
+        """Sample ``s`` of op ``i``'s output, read outside its group."""
+        if src.ops[i].kind == "input":
+            return inputs[s]
+        gid = op_owner[i]
+        return taps[gid][s] if cg[gid].tap == i and gid in taps \
+            else outs[gid][s]
 
     for g in cg:
         main, side = _main_and_skip_preds(cg, g, op_owner)
         wp = _weight_pred(cg, g, op_owner)
         spec = _group_spec(cg, g)
-        q = quant[g.idx]
+        q = quant[g.param_key]
+        if ordered_tail(cg, g):
+            res, tap, acc_dbg, peak = [], [], [], 0
+            for s in range(B):
+                y, t, acc, p = _ordered_sample(
+                    cg, g, weights, biases, q, norms, mm, faults, s,
+                    lambda i: operand(i, s))
+                res.append(y)
+                tap.append(t)
+                acc_dbg.append(acc)
+                peak = max(peak, p)
+            outs[g.idx] = np.stack(res)
+            if g.tap is not None:
+                taps[g.idx] = np.stack(tap)
+            if return_acc:
+                accs[g.idx] = np.stack(acc_dbg)
+                glu_peak[g.idx] = peak
+            continue
         res = []
         acc_dbg = []
         vops = _vops(cg, g)
@@ -198,7 +277,7 @@ def run_reference(cg: CondensedGraph, weights: Dict[int, np.ndarray],
                     bool(anchor_op.attrs.get("transpose_weights"))
                 ).astype(np.int32)
             else:
-                W = weights[g.idx]
+                W = weights[g.param_key]
                 if faults is not None:
                     W = faults.corrupt_weight_matrix(g.idx, W)
                 W = W.astype(np.int32)
@@ -269,8 +348,7 @@ def run_reference(cg: CondensedGraph, weights: Dict[int, np.ndarray],
                     leave_i32()
                     seg = anchor_op.gemm_n if anchor_op is not None \
                         else y.shape[-1]
-                    shp = y.shape
-                    y = vecsem.softmax_i8(y.reshape(-1, seg)).reshape(shp)
+                    y = _softmax(y, n, seg, causal_softmax(cg, g))
                 elif op == "layernorm":
                     leave_i32()
                     row = y.shape[-1]
@@ -294,7 +372,78 @@ def run_reference(cg: CondensedGraph, weights: Dict[int, np.ndarray],
             accs[g.idx] = np.stack(acc_dbg)
     if return_acc:
         outs["acc"] = accs          # type: ignore[assignment]
+        outs["glu"] = glu_peak      # type: ignore[assignment]
     return outs
+
+
+def _softmax(y: np.ndarray, n: int, seg: int, causal: bool) -> np.ndarray:
+    """Softmax per head segment of ``seg`` on int8 rows of ``n``; causal:
+    row ``r`` sees keys ``0..r`` (:func:`vecsem.causal_softmax_i8`)."""
+    if causal:
+        return vecsem.causal_softmax_i8(y.reshape(-1, n), seg).reshape(
+            y.shape)
+    return vecsem.softmax_i8(y.reshape(-1, seg)).reshape(y.shape)
+
+
+def _ordered_sample(cg: CondensedGraph, g, weights, biases,
+                    q: QuantParams, norms, mm: MatmulFn, faults, s: int,
+                    operand: Callable[[int], np.ndarray]):
+    """One sample of an ordered-tail group (a linear anchor): input-side
+    norms, the INT32 contraction with bias and a leading relu, the
+    requantization, then every fused op in graph order on int8 rows.
+    Returns ``(output, tap or None, accumulator, peak |glu product|)``.
+    """
+    src = cg.source
+    anchor = src.ops[g.anchor]
+    x = operand(_main_operand_op(cg, g))
+    for i in pre_ops(cg, g):
+        x = _apply_norm(x.reshape(-1, src.ops[i].out_shape[-1]),
+                        src.ops[i], norms)
+    W = weights[g.param_key]
+    if faults is not None:
+        W = faults.corrupt_weight_matrix(g.idx, W)
+    acc = mm(x.reshape(-1, W.shape[0]).astype(np.int32),
+             W.astype(np.int32))
+    if faults is not None:
+        acc = faults.corrupt_acc(acc, g.idx, s)
+    acc_dbg = acc.copy()
+    head = list(_vops(cg, g))
+    if head[:1] == ["bias"]:
+        acc = acc + biases[g.param_key].astype(np.int32)[None, :]
+        head = head[1:]
+    if head[:1] == ["relu"]:
+        acc = np.maximum(acc, 0)
+    y = quantize(acc, q)
+    tap, peak = None, 0
+    for op in (src.ops[i] for i in _tail_ops(cg, g)):
+        other = [i for i in op.inputs if i not in g.op_ids]
+        if op.kind == "add":
+            y = _sat_add(y, operand(other[0]).reshape(y.shape))
+        elif op.kind == "glu":
+            gate = operand(other[0]).reshape(y.shape)
+            p = vecsem.silu_i8(gate).astype(np.int64) * y.astype(np.int64)
+            peak = max(peak, int(np.abs(p).max(initial=0)))
+            y = vecsem.glu_i8(gate, y, q.mul_shift)
+        elif op.kind == "rmsnorm":
+            y = _apply_norm(y, op, norms)
+        elif op.kind == "rope":
+            y = _rope(y, op)
+        elif op.kind == "softmax":
+            y = _softmax(y, y.shape[-1], anchor.gemm_n,
+                         bool(op.attrs.get("causal")))
+        elif op.kind == "layernorm":
+            y = vecsem.layernorm_i8(y)
+        elif op.kind in ("silu", "sigmoid", "gelu"):
+            y = getattr(vecsem, f"{op.kind}_i8")(y)
+        elif op.kind == "relu":
+            y = np.maximum(y, 0)
+        else:
+            raise NotImplementedError(
+                f"oracle: fused op {op.kind!r} unsupported on the "
+                f"ordered tail")
+        if op.idx == g.tap:
+            tap = y.copy()
+    return y.reshape(-1), tap, acc_dbg, peak
 
 
 def _vops(cg: CondensedGraph, g) -> Tuple[str, ...]:
@@ -302,7 +451,8 @@ def _vops(cg: CondensedGraph, g) -> Tuple[str, ...]:
     out = []
     for i in g.op_ids:
         op = src.ops[i]
-        if op.is_mvm or op.kind in ("bn", "flatten", "identity"):
+        if op.is_mvm or op.kind in ("bn", "flatten", "identity") \
+                or op.attrs.get("pre"):
             continue
         out.append(op.kind)
     return tuple(out)
@@ -341,7 +491,7 @@ def random_init(cg: CondensedGraph, batch: int = 1, seed: int = 0
     biases: Dict[int, np.ndarray] = {}
     lo, hi = -6, 7
     for g in cg:
-        if g.anchor is None:
+        if g.anchor is None or g.owner is not None:
             continue
         op = src.ops[g.anchor]
         if op.kind == "conv":
@@ -368,21 +518,35 @@ def random_init(cg: CondensedGraph, batch: int = 1, seed: int = 0
 
 def auto_quant(cg: CondensedGraph, weights: Dict[int, np.ndarray],
                biases: Dict[int, np.ndarray],
-               inputs: np.ndarray) -> Dict[int, QuantParams]:
+               inputs: np.ndarray,
+               norms: Optional[Dict[int, np.ndarray]] = None
+               ) -> Dict[int, QuantParams]:
     """Pick per-group shifts that keep outputs in a healthy int8 range
     (fixed-point iteration of the oracle: downstream ranges depend on
-    upstream quantization)."""
-    qp = {g.idx: QuantParams(scale=1, shift=0) for g in cg}
+    upstream quantization).  Loop-shared groups get one shift per owner,
+    sized on the largest of their accumulators; a gated product's
+    ``mul_shift`` is sized the same way on its products."""
+    def shift_for(peak: int) -> int:
+        return (min(max(0, math.ceil(math.log2(peak / 100))), 30)
+                if peak > 100 else 0)
+
+    keys = sorted({g.param_key for g in cg})
+    qp = {k: QuantParams(scale=1, shift=0) for k in keys}
     for _ in range(3):
         outs = run_reference(cg, weights, biases, qp, inputs,
-                             return_acc=True)
+                             return_acc=True, norms=norms)
         accs = outs["acc"]          # type: ignore[index]
-        new = {}
+        glu = outs["glu"]           # type: ignore[index]
+        peak: Dict[int, int] = {}
+        mpeak: Dict[int, int] = {}
         for g in cg:
-            peak = max(1, int(np.abs(accs[g.idx]).max()))
-            shift = (max(0, math.ceil(math.log2(peak / 100)))
-                     if peak > 100 else 0)
-            new[g.idx] = QuantParams(scale=1, shift=min(shift, 30))
+            k = g.param_key
+            peak[k] = max(peak.get(k, 1),
+                          int(np.abs(accs[g.idx]).max()))
+            mpeak[k] = max(mpeak.get(k, 1), glu.get(g.idx, 1))
+        new = {k: QuantParams(scale=1, shift=shift_for(peak[k]),
+                              mul_shift=shift_for(mpeak[k]))
+               for k in keys}
         if new == qp:
             break
         qp = new

@@ -578,16 +578,38 @@ class Simulator:
             view = lm if i8 else lm.view(np.int32)
             view[di] = np.maximum(view[ai], 0)
             return
-        if fn in ("softmax", "layernorm", "gelu"):
+        if fn in ("softmax", "layernorm", "gelu", "silu", "sigmoid"):
             # transformer tails: int8 row-segment semantics shared with
             # the oracle through repro.core.vecsem (bit-exact contract)
             if not i8:
                 raise SimError(f"functional mode: {fn} requires int8 "
                                f"operands")
             x = lm[ai]                       # (rep, vlen) row segments
+            if fn == "layernorm":
+                lm[di] = vecsem.layernorm_i8(
+                    x, center=not flags & FLAGS["rms"])
+                return
             lm[di] = {"softmax": vecsem.softmax_i8,
-                      "layernorm": vecsem.layernorm_i8,
-                      "gelu": vecsem.gelu_i8}[fn](x)
+                      "gelu": vecsem.gelu_i8,
+                      "silu": vecsem.silu_i8,
+                      "sigmoid": vecsem.sigmoid_i8}[fn](x)
+            return
+        if fn == "rope":
+            # int8 head segments rotated by their rows' int16 Q14 table
+            if not i8:
+                raise SimError("functional mode: rope requires int8 "
+                               "operands")
+            ti = (b_ + reps[:, None] * sb) // 2 + lane[None, :]
+            lm[di] = vecsem.rope_i8(lm[ai], lm.view(np.int16)[ti])
+            return
+        if fn == "mac" and i8:
+            # int8 x int8 products accumulate into the int32 dst
+            acc = lm.view(np.int32)
+            bi8 = idx(b_, sb, tb, 1)
+            d32 = idx(dst, sd, td, 4)
+            z = acc[d32].astype(np.int64) + (
+                lm[ai].astype(np.int64) * lm[bi8].astype(np.int64))
+            acc[d32] = np.clip(z, -2**31, 2**31 - 1).astype(np.int32)
             return
 
         bi = idx(b_, sb, tb, esz)

@@ -42,7 +42,8 @@ from .arch import ChipConfig
 from .codegen import (_conv_rows_to_compute, _core_columns, _in_row_bytes,
                       _main_and_skip_preds, _needed_in_rows, _out_geometry,
                       _owned_out_rows, _pooled_rows, _side_pre_reduce,
-                      _side_rows)
+                      _side_rows, _tail_ops, causal_softmax, ordered_tail,
+                      pre_ops, softmax_calls)
 from .energy import DEFAULT_TABLE, EnergyTable, energy_breakdown
 from .graph import CondensedGraph
 from .machine import Calibration, MachineModel, machine_for
@@ -158,6 +159,53 @@ def _chunk_shapes(sched: OpSchedule, rep: ReplicaPlan,
     widths = [min(span - c0, sched.m_chunk)
               for c0 in range(0, span, sched.m_chunk)]
     return (1, widths) if widths else (0, [])
+
+
+def _softmax_cycles(m: MachineModel, o0: int, o1: int, n: int, seg: int,
+                    causal: bool) -> float:
+    """Vector cycles of codegen's softmax instructions
+    (:func:`~repro.core.codegen.softmax_calls`)."""
+    return sum(m.vector_cycles(fn, vlen * rep)
+               for fn, _, vlen, rep in softmax_calls(o0, o1, n, seg,
+                                                     causal))
+
+
+def _ordered_tail_cycles(cg: CondensedGraph, g, sched: OpSchedule,
+                         m: MachineModel, in_rows: int, o0: int,
+                         o1: int) -> float:
+    """Vector cycles of an ordered tail on the assembly core, as
+    codegen's ``_emit_ordered_tail`` emits it on output rows ``[o0,
+    o1)`` (input-side norms run on every core at once, so one core's
+    share is counted)."""
+    src = cg.source
+    N = sched.n_total
+    rows = o1 - o0
+    total = rows * N
+    cyc = 0.0
+    for _ in pre_ops(cg, g):
+        cyc += (m.vector_cycles("layernorm", in_rows * sched.k_total)
+                + m.vector_cycles("mul", in_rows * sched.k_total))
+    if rows <= 0:
+        return cyc
+    for i in _tail_ops(cg, g):
+        kind = src.ops[i].kind
+        if kind == "glu":
+            cyc += sum(m.vector_cycles(f, total)
+                       for f in ("silu", "zero", "mac", "quant"))
+        elif kind == "rmsnorm":
+            cyc += (m.vector_cycles("layernorm", total)
+                    + m.vector_cycles("mul", total))
+        elif kind == "rope":
+            hd = src.ops[i].attrs["head_dim"]
+            cyc += (N // hd) * m.vector_cycles("rope", rows * hd)
+        elif kind == "softmax":
+            cyc += _softmax_cycles(m, o0, o1, N, g.gemm_n,
+                                   bool(src.ops[i].attrs.get("causal")))
+        else:
+            cyc += m.vector_cycles(kind, total)
+        if i == g.tap:
+            cyc += m.vector_cycles("mov", total)
+    return cyc
 
 
 def _profile(cg: CondensedGraph, sched: OpSchedule, rep: ReplicaPlan,
@@ -301,10 +349,13 @@ def _profile(cg: CondensedGraph, sched: OpSchedule, rep: ReplicaPlan,
         p.vec += post * nrows
 
     # -- fused tail (once per sample, on the asm core) ---------------------
-    has_side_op = "add" in vo or "mul" in vo
+    ordered = ordered_tail(cg, g)
+    has_side_op = not ordered and ("add" in vo or "mul" in vo)
     side_pre = _side_pre_reduce(sched)
     o0, o1 = _owned_out_rows(cg, sched, rep)
     _, out_row_nb, _ = _out_geometry(cg, sched)
+    if ordered:
+        p.vec += _ordered_tail_cycles(cg, g, sched, m, r1 - r0, o0, o1)
     if has_side_op:
         lo, hi, row_nb = (k0, k1, krow_nb) if side_pre \
             else (o0, o1, out_row_nb)
@@ -315,7 +366,13 @@ def _profile(cg: CondensedGraph, sched: OpSchedule, rep: ReplicaPlan,
                 p.vec += m.vector_cycles("relu", (hi - lo) * row_nb)
     for vop in vo:
         # fused special tails (softmax/layernorm/gelu) on the asm core
-        if vop in ("softmax", "layernorm", "gelu") and o1 > o0:
+        if ordered or vop not in ("softmax", "layernorm", "gelu") \
+                or o1 <= o0:
+            continue
+        if vop == "softmax":
+            p.vec += _softmax_cycles(m, o0, o1, out_row_nb, g.gemm_n,
+                                     causal_softmax(cg, g))
+        else:
             p.vec += m.vector_cycles(vop, (o1 - o0) * out_row_nb)
     if sched.pool is not None:
         pl = sched.pool

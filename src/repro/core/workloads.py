@@ -19,7 +19,8 @@ from .graph import Graph, Op
 
 __all__ = [
     "resnet18", "vgg19", "mobilenetv2", "efficientnetb0",
-    "transformer_lm", "transformer_decode", "tiny_cnn", "WORKLOADS",
+    "transformer_lm", "transformer_decode", "tiny_cnn", "arch_lm",
+    "WORKLOADS",
     "build",
 ]
 
@@ -313,6 +314,152 @@ def deepseek_proxy(n_layers: int = 8, d_model: int = 768, n_heads: int = 12,
     return g
 
 
+# ---------------------------------------------------------------------------
+# The normal path for model configurations: ArchConfig -> Graph
+# ---------------------------------------------------------------------------
+
+
+def arch_lm(arch: str, seq: int = 256, layers: Optional[int] = None,
+            **overrides) -> Graph:
+    """Lower a dense :class:`~repro.configs.base.ArchConfig` to a graph.
+
+    ``arch`` names a configuration (:func:`repro.configs.cim_arch`);
+    ``overrides`` replace any of its fields (JSON-able numbers, e.g. a
+    test's ``d_model=64``).  ``layers`` keeps the last ``layers``
+    layers of the stack (a pipeline's last stage, which also applies the
+    final norm and the exit gate); every one of the configuration's
+    ``loop_steps`` runs over that slice, and a step's output state feeds
+    the next step.
+
+    Per layer: RMSNorm N1 -> q/k/v linears -> RoPE on q and k ->
+    dynamic-weight scores -> causal softmax (token ``r`` attends to
+    tokens ``0..r``) -> ctx -> o linear -> (sandwich:
+    RMSNorm N2) -> residual add -> RMSNorm N3 -> gate/up linears ->
+    ``silu(gate) * up`` (``glu``) -> down linear -> (sandwich: N4) ->
+    residual add.  After the last layer of the stack: the final RMSNorm
+    (its output is the step's state) and, with ``exit_gate``, a
+    ``d_model -> 1`` linear and a sigmoid per step.  Each norm of the
+    residual stream fuses into the group that produced the stream, so
+    the stream is that group's *tap* and its norm the group's output;
+    only a norm on the graph input runs input-side (``attrs['pre']``)
+    in each of the q/k/v groups.  Ops of loop steps after the first
+    name the step-0 op whose parameters they reuse (``attrs['owner']``).
+    """
+    import dataclasses
+
+    from ..configs import cim_arch
+    from ..obs import span
+
+    cfg = cim_arch(arch)
+    if overrides:
+        cfg = dataclasses.replace(cfg, **overrides)
+    if (cfg.family != "dense" or cfg.moe or cfg.mla or cfg.ssm
+            or cfg.block_pattern != "A" or cfg.encoder_layers
+            or cfg.vision_tokens):
+        raise ValueError(f"{cfg.name}: the normal path lowers dense "
+                         f"attention stacks only")
+    if cfg.n_kv_heads != cfg.n_heads:
+        raise ValueError(f"{cfg.name}: grouped-query attention "
+                         f"({cfg.n_kv_heads} KV heads for {cfg.n_heads}) "
+                         f"is not lowered")
+    if cfg.norm != "rmsnorm" or cfg.act != "swiglu":
+        raise ValueError(f"{cfg.name}: the normal path lowers RMSNorm "
+                         f"and SwiGLU blocks")
+    if cfg.exit_gate and cfg.early_exit_threshold < 1:
+        raise ValueError(f"{cfg.name}: an early exit is data-dependent "
+                         f"control flow; the lowering runs every step")
+    n = layers if layers is not None else cfg.n_layers
+    if not 0 < n <= cfg.n_layers:
+        raise ValueError(f"{cfg.name}: {n} of its {cfg.n_layers} layers")
+    first = cfg.n_layers - n
+    d, heads, hd = cfg.d_model, cfg.n_heads, cfg.hd
+    if heads * hd != d:
+        raise ValueError(f"{cfg.name}: {heads} heads of {hd} != {d}")
+    steps = cfg.loop_steps
+    with span("repro.flow.lower", layers=n, loop_steps=steps, seq=seq):
+        g = Graph(f"{cfg.name}_{n}L_x{steps}_s{seq}")
+        owners: Dict[str, int] = {}
+
+        def shared(key: str, i: int) -> int:
+            """Record op ``i`` as the owner of ``key``'s parameters, or
+            point it at the existing owner (``attrs['param']`` keeps the
+            key: weights and gains are looked up by it)."""
+            g.ops[i].attrs["param"] = key
+            if key in owners:
+                g.ops[i].attrs["owner"] = owners[key]
+            else:
+                owners[key] = i
+            return i
+
+        def norm(name: str, key: str, src: int, pre: bool = False) -> int:
+            i = g.unary(name, "rmsnorm", src)
+            if pre:
+                g.ops[i].attrs["pre"] = True
+            return shared(key, i)
+
+        def linear(name: str, key: str, src: int, cout: int) -> int:
+            return shared(key, g.linear(name, src, cout=cout, bias=False))
+
+        def rope(name: str, src: int) -> int:
+            i = g.unary(name, "rope", src)
+            g.ops[i].attrs.update(head_dim=hd, theta=float(cfg.rope_theta))
+            return i
+
+        def attn_matmul(name: str, key: str, a: int, w: int,
+                        transpose: bool) -> int:
+            shape = (heads, seq, seq) if transpose else (seq, d)
+            i = g.add(Op(name=name, kind="matmul", inputs=(a, w),
+                         out_shape=shape, gemm_m=seq,
+                         gemm_k=hd if transpose else seq,
+                         gemm_n=seq if transpose else hd, groups=heads,
+                         attrs={"dynamic_weights": True,
+                                "transpose_weights": transpose}))
+            return shared(key, i)
+
+        sandwich = cfg.norm_placement == "sandwich"
+        h = g.input("state", (seq, d))      # the residual stream
+        hn: Optional[int] = None            # N1(h), once a group holds h
+        for t in range(steps):
+            for li in range(first, first + n):
+                p, L = f"s{t}.l{li}", f"l{li}"
+
+                def n1(which: str) -> int:
+                    if hn is not None:
+                        return hn
+                    return norm(f"{p}.{which}.n1", f"{L}.n1", h, pre=True)
+
+                q = rope(f"{p}.q.rope",
+                         linear(f"{p}.q", f"{L}.q", n1("q"), d))
+                k = rope(f"{p}.k.rope",
+                         linear(f"{p}.k", f"{L}.k", n1("k"), d))
+                v = linear(f"{p}.v", f"{L}.v", n1("v"), d)
+                sc = attn_matmul(f"{p}.scores", f"{L}.scores", q, k, True)
+                sm = g.unary(f"{p}.softmax", "softmax", sc)
+                g.ops[sm].attrs["causal"] = True    # a decoder's prefill
+                ctx = attn_matmul(f"{p}.ctx", f"{L}.ctx", sm, v, False)
+                o = linear(f"{p}.o", f"{L}.o", ctx, d)
+                if sandwich:
+                    o = norm(f"{p}.n2", f"{L}.n2", o)
+                h1 = g.eltwise(f"{p}.res1", "add", o, h)
+                x = norm(f"{p}.n3", f"{L}.n3", h1)
+                gate = linear(f"{p}.gate", f"{L}.gate", x, cfg.d_ff)
+                up = linear(f"{p}.up", f"{L}.up", x, cfg.d_ff)
+                y = g.eltwise(f"{p}.glu", "glu", up, gate)
+                y = linear(f"{p}.down", f"{L}.down", y, d)
+                if sandwich:
+                    y = norm(f"{p}.n4", f"{L}.n4", y)
+                h = g.eltwise(f"{p}.res2", "add", y, h1)
+                if li == cfg.n_layers - 1:
+                    h = norm(f"s{t}.final_norm", "final_norm", h)
+                    if cfg.exit_gate:
+                        e = linear(f"s{t}.exit", "exit", h, 1)
+                        g.unary(f"s{t}.exit.sigmoid", "sigmoid", e)
+                if t + 1 < steps or li + 1 < first + n:
+                    nxt = first if li + 1 == first + n else li + 1
+                    hn = norm(f"{p}.n1_next", f"l{nxt}.n1", h)
+    return g
+
+
 WORKLOADS = {
     "resnet18": resnet18,
     "vgg19": vgg19,
@@ -322,6 +469,7 @@ WORKLOADS = {
     "transformer_decode": transformer_decode,
     "tiny_cnn": tiny_cnn,
     "deepseek_proxy": deepseek_proxy,
+    "arch": arch_lm,
 }
 
 

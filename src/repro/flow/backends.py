@@ -199,7 +199,8 @@ class PallasFuncBackend(Backend):
 
     ``weights``/``biases``/``inputs``/``quant`` default to
     :func:`repro.core.ref.random_init` + ``auto_quant`` draws, making
-    ``artifact.evaluate("func:pallas")`` self-contained.
+    ``artifact.evaluate("func:pallas")`` self-contained; ``norms`` (the
+    RMSNorm gains by ``Op.param_owner``) default to ones.
     """
 
     name = "func:pallas"
@@ -208,11 +209,11 @@ class PallasFuncBackend(Backend):
     def evaluate(self, artifact: Any, weights: Any = None,
                  biases: Any = None, inputs: Any = None,
                  quant: Any = None, check: bool = True,
-                 seed: int = 0, faults: Any = None,
+                 seed: int = 0, faults: Any = None, norms: Any = None,
                  **kw: Any) -> EvalReport:
         if kw:
             raise TypeError(f"func:pallas backend takes weights/biases/"
-                            f"inputs/quant/check/seed/faults, "
+                            f"inputs/quant/norms/check/seed/faults, "
                             f"got {sorted(kw)}")
         from ..core import ref
         t0 = time.perf_counter()
@@ -227,19 +228,20 @@ class PallasFuncBackend(Backend):
         else:
             batch = int(inputs.shape[0])
         if quant is None:
-            quant = ref.auto_quant(cg, weights, biases, inputs)
+            quant = ref.auto_quant(cg, weights, biases, inputs, norms=norms)
         # ``faults`` (a repro.faults.FaultSet) corrupts both oracles
         # identically, so the bit-equality check stays meaningful on
         # faulty runs — it validates the kernel, not the fault model
         with span("repro.ref.forward"):
             outs = ref.run_reference(cg, weights, biases, quant, inputs,
-                                     matmul=_pallas_matmul, faults=faults)
+                                     matmul=_pallas_matmul, faults=faults,
+                                     norms=norms)
         if check:
             with span("repro.ref.oracle") as sp:
                 tally = ref.ContractionTally()
                 want = ref.run_reference(cg, weights, biases, quant,
                                          inputs, matmul=tally,
-                                         faults=faults)
+                                         faults=faults, norms=norms)
                 sp.set_metadata(f64=tally.f64)
                 for gid, arr in want.items():
                     got = outs[gid]

@@ -98,6 +98,10 @@ class Pass:
     def dump(self, out: Any) -> Optional[Dict[str, Any]]:
         return None
 
+    def counters(self, out: Any) -> Dict[str, int]:
+        """Counters recorded on the pass's ``repro.flow.<name>`` span."""
+        return {}
+
     def write_dump(self, out: Any, dump_dir: str, key: str) -> \
             Optional[str]:
         doc = self.dump(out)
@@ -240,6 +244,10 @@ class CodegenPass(Pass):
 
     def apply(self, ctx: PipelineContext, out: CompiledModel) -> None:
         ctx.model = out
+
+    def counters(self, out: CompiledModel) -> Dict[str, int]:
+        return {"weight_bytes": out.weight_bytes,
+                "weight_load_bytes": out.weight_load_bytes}
 
     def summarize(self, out: CompiledModel) -> str:
         return (f"{out.total_instrs} instrs across "

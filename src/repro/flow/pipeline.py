@@ -163,9 +163,10 @@ class Artifact:
         return _dc.replace(self, options=opts, trace=list(self.trace),
                            _model=keep_model)
 
-    def build_gmem_image(self, weights, biases, inputs) -> np.ndarray:
+    def build_gmem_image(self, weights, biases, inputs,
+                         norms=None) -> np.ndarray:
         return self.ensure_model().build_gmem_image(weights, biases,
-                                                    inputs)
+                                                    inputs, norms)
 
     def output_addr(self, gid: int, sample: int) -> Tuple[int, int]:
         return self.ensure_model().output_addr(gid, sample)
@@ -282,6 +283,7 @@ class Pipeline:
                 out = p.run(ctx)
                 if p.cacheable:
                     self._cache_put(key, out)
+            sp.set_metadata(**p.counters(out))
             dump_path = None
             if ctx.options.dump_dir:  # dump cache hits too — the dump
                 dump_path = p.write_dump(out, ctx.options.dump_dir, key)

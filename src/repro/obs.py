@@ -20,7 +20,7 @@ from __future__ import annotations
 import sys
 from typing import Any
 
-__all__ = ["span"]
+__all__ = ["span", "recording"]
 
 
 class _Off:
@@ -49,3 +49,10 @@ def span(name: str, **args: Any) -> Any:
     if jax is None:
         return _OFF
     return jax.profiler.TraceAnnotation(name, **args)
+
+
+def recording() -> bool:
+    """Is a ``jax.profiler`` session recording spans?  (Work done only
+    for a trace's reader checks this first.)"""
+    jax = sys.modules.get("jax")
+    return jax is not None and jax.profiler.TraceAnnotation.is_enabled()
