@@ -13,7 +13,7 @@ shape** and, crucially, makes the machine's timing constants a
   with donated input buffers and returns per-instruction latencies plus
   the resolved register/sreg/occupancy values; the host then assembles
   replay items with the *identical numpy expressions* the numpy engine
-  uses (:func:`vectorsim._finish_decode`), so every reported number —
+  uses (:class:`vectorsim._StagePlan`), so every reported number —
   cycles, stage_cycles, unit_busy, events, instrs — is bit-identical.
 * :class:`FleetStageDecoder` — many machines.  The timing constants
   stack into a :class:`MachineTables` pytree and the same device pass is
@@ -47,6 +47,8 @@ back to the scalar interpreter per stage, unchanged.
 from __future__ import annotations
 
 import hashlib
+import pickle
+from collections import Counter
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -55,7 +57,7 @@ from ..obs import recording, span
 from .isa import Isa, Program
 from .machine import MachineModel
 from .vectorsim import (
-    StageDecoder, DecodeUnsupported, _DecodedStage, _Prep, _finish_decode,
+    StageDecoder, DecodeUnsupported, _DecodedStage, _Prep, _StagePlan,
     replay_stage, _END, _K_VEC, _K_MVM, _K_WLOAD, _K_BCAST,
     _K_SEND, _K_RECV, _K_GLD, _K_GST, _K_SYNC, _K_HALT,
     _S_VLEN, _S_VREP, _S_CHANNEL, _S_MASK_LO, _S_MASK_HI,
@@ -337,99 +339,122 @@ def _call_exec(fn: Any, sc: Tuple[np.ndarray, ...],
     return lat[..., :n], res
 
 
-def _finish_from_device(out: _DecodedStage, pr: _Prep, dec: StageDecoder,
-                        m: MachineModel, ilat: np.ndarray,
-                        res: Dict[str, np.ndarray]) -> None:
-    """Numpy back half: float latencies, event ledgers, boundary items,
-    replay plan.
+class _FinishPlan:
+    """Machine-independent half of the host finish: event ledgers,
+    boundary items and the stage's :class:`vectorsim._StagePlan`.
 
-    ``ilat`` holds the device's int64 vector / MVM latencies; the rest
-    are priced here.  Every expression is copied verbatim from the
+    Built once per stage from the prep and the device's resolved
+    operands, which the vmapped call returns once for the whole fleet.
+    What belongs to one machine is its latency row (:meth:`lat_rows`)
+    and the macros per group that scale its macro passes; :meth:`fill`
+    takes exactly those.  Every expression is copied verbatim from the
     numpy engine's ``decode_stage`` (same dtypes, same `.sum()` order),
     so the totals are bit-identical given identical per-instruction
     inputs.
     """
-    with span("repro.sim.finish"):
-        _finish(out, pr, dec, m, ilat, res)
 
+    __slots__ = ("op", "dpos", "lpos", "rows", "bcast", "bsize",
+                 "ev_tot", "mvm_passes", "stage")
 
-def _finish(out: _DecodedStage, pr: _Prep, dec: StageDecoder,
-            m: MachineModel, ilat: np.ndarray,
-            res: Dict[str, np.ndarray]) -> None:
-    op, kind, col = pr.op, pr.kind, pr.col
-    ev_tot = [0.0] * 4
-    ev_cnt = [0] * 4
-    lat = dec.clat[op].copy()
-    dpos = np.flatnonzero((kind == _K_VEC) | (kind == _K_MVM))
-    lat[dpos] = ilat[dpos]                # int64 -> float64, exact < 2^53
+    def __init__(self, pr: _Prep, dec: StageDecoder,
+                 res: Dict[str, np.ndarray]) -> None:
+        op, kind, col = pr.op, pr.kind, pr.col
+        ev_tot = [0.0] * 4
+        ev_cnt = [0] * 4
+        self.op = op
+        self.dpos = np.flatnonzero((kind == _K_VEC) | (kind == _K_MVM))
 
-    # ---- S_LD / S_ST ledger traffic (4 B words) --------------------
-    n_mem = int(((op == dec.id_sld) | (op == dec.id_sst)).sum())
-    ev_tot[0] += 4.0 * n_mem
-    ev_cnt[0] += n_mem
+        # ---- S_LD / S_ST ledger traffic (4 B words) --------------------
+        n_mem = int(((op == dec.id_sld) | (op == dec.id_sst)).sum())
+        ev_tot[0] += 4.0 * n_mem
+        ev_cnt[0] += n_mem
 
-    # ---- vector ops ------------------------------------------------
-    vpos = np.flatnonzero(kind == _K_VEC)
-    if len(vpos):
-        n_el = (np.maximum(res["vlen"][vpos], 1)
-                * np.maximum(res["vrep"][vpos], 1))
-        esz = np.where(col("flags")[vpos] & _I8_FLAG, 1, 4)
-        ev_tot[0] += float((n_el * esz * 2).sum())
-        ev_tot[3] += float(n_el.sum())
-        ev_cnt[0] += len(vpos)
-        ev_cnt[3] += len(vpos)
+        # ---- vector ops ------------------------------------------------
+        vpos = np.flatnonzero(kind == _K_VEC)
+        if len(vpos):
+            n_el = (np.maximum(res["vlen"][vpos], 1)
+                    * np.maximum(res["vrep"][vpos], 1))
+            esz = np.where(col("flags")[vpos] & _I8_FLAG, 1, 4)
+            ev_tot[0] += float((n_el * esz * 2).sum())
+            ev_tot[3] += float(n_el.sum())
+            ev_cnt[0] += len(vpos)
+            ev_cnt[3] += len(vpos)
 
-    # ---- CIM_LOAD --------------------------------------------------
-    lpos = np.flatnonzero(kind == _K_WLOAD)
-    if len(lpos):
-        rows = col("rows")[lpos]
-        lat[lpos] = m.weight_load_cycles_array(rows)
-        nlen = np.maximum(res["nlen"][lpos], 1)
-        wl = float((rows * nlen).sum())
-        ev_tot[0] += wl
-        ev_tot[1] += wl
-        ev_cnt[0] += len(lpos)
-        ev_cnt[1] += len(lpos)
+        # ---- CIM_LOAD --------------------------------------------------
+        self.lpos = lpos = np.flatnonzero(kind == _K_WLOAD)
+        self.rows = rows = col("rows")[lpos]
+        if len(lpos):
+            nlen = np.maximum(res["nlen"][lpos], 1)
+            wl = float((rows * nlen).sum())
+            ev_tot[0] += wl
+            ev_tot[1] += wl
+            ev_cnt[0] += len(lpos)
+            ev_cnt[1] += len(lpos)
 
-    # ---- CIM_MVM ---------------------------------------------------
-    mpos = np.flatnonzero(kind == _K_MVM)
-    if len(mpos):
-        rep = col("rep")[mpos]
-        mask = ((res["mask_lo"][mpos] & 0xFFFF)
-                | (res["mask_hi"][mpos] << 16))
-        act = res["loaded"][mpos] & mask
-        active = np.zeros(len(mpos), dtype=np.int64)
-        for b in range(32):
-            active += (act >> b) & 1
-        ev_tot[2] += float((rep * active).sum() * m.macros_per_group)
-        seg = res["seg_in"][mpos] + res["seg_out"][mpos]
-        ev_tot[0] += float((rep * seg).sum())
-        ev_cnt[0] += len(mpos)
-        ev_cnt[2] += len(mpos)
+        # ---- CIM_MVM: macro passes scale by the machine's group size ---
+        mpos = np.flatnonzero(kind == _K_MVM)
+        self.mvm_passes = None
+        if len(mpos):
+            rep = col("rep")[mpos]
+            mask = ((res["mask_lo"][mpos] & 0xFFFF)
+                    | (res["mask_hi"][mpos] << 16))
+            act = res["loaded"][mpos] & mask
+            active = np.zeros(len(mpos), dtype=np.int64)
+            for b in range(32):
+                active += (act >> b) & 1
+            self.mvm_passes = (rep * active).sum()
+            seg = res["seg_in"][mpos] + res["seg_out"][mpos]
+            ev_tot[0] += float((rep * seg).sum())
+            ev_cnt[0] += len(mpos)
+            ev_cnt[2] += len(mpos)
+        self.ev_tot = ev_tot
 
-    # ---- BCAST: sender-side injection occupancy --------------------
-    bcast = np.flatnonzero(kind == _K_BCAST)
-    if len(bcast):
-        lat[bcast] = m.send_issue_cycles_array(res["size"][bcast])
+        # ---- BCAST: sender-side injection occupancy --------------------
+        self.bcast = bcast = np.flatnonzero(kind == _K_BCAST)
+        self.bsize = res["size"][bcast]
 
-    # ---- boundary items --------------------------------------------
-    bitems: Dict[int, tuple] = {}
-    for tag in (_K_SEND, _K_RECV):
-        kpos = np.flatnonzero(kind == tag)
-        for p, c, s, st in zip(kpos.tolist(),
-                               res["core"][kpos].tolist(),
-                               res["size"][kpos].tolist(),
-                               res["chan"][kpos].tolist()):
-            bitems[p] = (tag, c, s, st)
-    for tag in (_K_GLD, _K_GST):
-        kpos = np.flatnonzero(kind == tag)
-        for p, s in zip(kpos.tolist(), res["size"][kpos].tolist()):
-            bitems[p] = (tag, s)
-    sync = np.flatnonzero(kind == _K_SYNC)
-    for p, b in zip(sync.tolist(), col("barrier")[sync].tolist()):
-        bitems[p] = (_K_SYNC, b)
+        # ---- boundary items --------------------------------------------
+        bitems: Dict[int, tuple] = {}
+        for tag in (_K_SEND, _K_RECV):
+            kpos = np.flatnonzero(kind == tag)
+            for p, c, s, st in zip(kpos.tolist(),
+                                   res["core"][kpos].tolist(),
+                                   res["size"][kpos].tolist(),
+                                   res["chan"][kpos].tolist()):
+                bitems[p] = (tag, c, s, st)
+        for tag in (_K_GLD, _K_GST):
+            kpos = np.flatnonzero(kind == tag)
+            for p, s in zip(kpos.tolist(), res["size"][kpos].tolist()):
+                bitems[p] = (tag, s)
+        sync = np.flatnonzero(kind == _K_SYNC)
+        for p, b in zip(sync.tolist(), col("barrier")[sync].tolist()):
+            bitems[p] = (_K_SYNC, b)
 
-    _finish_decode(out, pr, dec.unit[op], lat, bitems, ev_tot, ev_cnt)
+        self.stage = _StagePlan(pr, dec.unit[op], bitems, ev_cnt)
+
+    def lat_rows(self, decs: List[StageDecoder],
+                 machines: List[MachineModel],
+                 ilat: np.ndarray) -> np.ndarray:
+        """Every machine's per-instruction latencies, ``(machines,
+        rows)``: its constant latencies, the device's int64 vector /
+        MVM latencies (``ilat``, one row per machine), then its
+        weight-load and BCAST latencies priced here."""
+        lat = np.take(np.stack([d.clat for d in decs]), self.op, axis=1)
+        lat[:, self.dpos] = ilat[:, self.dpos]   # int64 -> float64, < 2^53
+        for row, m in zip(lat, machines):
+            if len(self.lpos):
+                row[self.lpos] = m.weight_load_cycles_array(self.rows)
+            if len(self.bcast):
+                row[self.bcast] = m.send_issue_cycles_array(self.bsize)
+        return lat
+
+    def fill(self, out: _DecodedStage, lat: np.ndarray,
+             macros_per_group: int) -> None:
+        """One machine's half: its latency row and group size."""
+        ev_tot = list(self.ev_tot)
+        if self.mvm_passes is not None:
+            ev_tot[2] += float(self.mvm_passes * macros_per_group)
+        self.stage.fill(out, lat, ev_tot)
 
 
 # ---------------------------------------------------------------------------
@@ -466,8 +491,11 @@ class JaxStageDecoder:
                 return out
             fn, _ = _exec_for(self.npdec, _reg_bucket(pr))
             cols = _device_cols(pr)
-        lat, res = _call_exec(fn, self._sc, cols, pr.n)
-        _finish_from_device(out, pr, self.npdec, self.m, lat, res)
+        ilat, res = _call_exec(fn, self._sc, cols, pr.n)
+        with span("repro.sim.finish", machines=1):
+            plan = _FinishPlan(pr, self.npdec, res)
+            lat = plan.lat_rows([self.npdec], [self.m], ilat[None])
+            plan.fill(out, lat[0], self.m.macros_per_group)
         return out
 
 
@@ -475,9 +503,10 @@ class FleetStageDecoder:
     """Batched decode of one stage for a whole fleet of machines.
 
     One prep, one vmapped device call over the stacked
-    :class:`MachineTables`, then one cheap numpy finish per machine —
-    the replay plans are exactly what each machine's own
-    ``Simulator(engine="jax")`` would build.
+    :class:`MachineTables`, the stage's machine-independent finish
+    once, then one numpy fill per distinct machine — the replay plans
+    are exactly what each machine's own ``Simulator(engine="jax")``
+    would build.
     """
 
     def __init__(self, isa: Isa, machines: List[MachineModel]) -> None:
@@ -515,17 +544,12 @@ class FleetStageDecoder:
 
     def decode_stage(self, programs: Dict[int, Program],
                      prep: Optional[_Prep] = None) -> List[_DecodedStage]:
+        """One decoded stage per machine.  Machines whose finished
+        latency rows, group sizes and unrolled programs are byte-equal
+        get the same object (:func:`replay_stage` only reads it)."""
         pr = prep if prep is not None else self.prep(programs)
-        n = len(self.machines)
-        lat = res = None
-        if pr.cids:
-            with span("repro.sim.decode_prep", rows=pr.n, machines=n):
-                _, fleet_fn = _exec_for(self.npdecs[0], _reg_bucket(pr))
-                cols = _device_cols(pr)
-            self._mark_repeat(cols)
-            lat, res = _call_exec(fleet_fn, self.tables.arrays, cols, pr.n)
-        outs: List[_DecodedStage] = []
-        for i, (m, dec) in enumerate(zip(self.machines, self.npdecs)):
+        bases: List[_DecodedStage] = []
+        for dec in self.npdecs:
             out = _DecodedStage()
             out.n_prog = dict(pr.n_prog)
             for cid in pr.empty:
@@ -534,10 +558,53 @@ class FleetStageDecoder:
                 with span("repro.sim.decode_prep", rows=pr.n, machines=1):
                     for cid, prog in pr.unroll:
                         dec.unroll_decode(prog, cid, out)
-            if pr.cids:
-                _finish_from_device(out, pr, dec, m, lat[i], res)
-            outs.append(out)
-        return outs
+            bases.append(out)
+        if not pr.cids:
+            return bases
+        with span("repro.sim.decode_prep", rows=pr.n,
+                  machines=len(self.machines)):
+            _, fleet_fn = _exec_for(self.npdecs[0], _reg_bucket(pr))
+            cols = _device_cols(pr)
+        self._mark_repeat(cols)
+        ilat, res = _call_exec(fleet_fn, self.tables.arrays, cols, pr.n)
+        return self._finish(pr, ilat, res, bases)
+
+    def _finish(self, pr: _Prep, ilat: np.ndarray,
+                res: Dict[str, np.ndarray],
+                bases: List[_DecodedStage]) -> List[_DecodedStage]:
+        """The host finish, once per distinct machine.
+
+        One ``repro.sim.finish`` span per decoded stage, its counter
+        ``machines`` the machines it serves; the first span also builds
+        the stage's shared half and every latency row.
+        """
+        mpg = [m.macros_per_group for m in self.machines]
+        with span("repro.sim.finish") as sp:
+            plan = _FinishPlan(pr, self.npdecs[0], res)
+            lat = plan.lat_rows(self.npdecs, self.machines, ilat)
+            # the key is all a fill reads of its machine, plus what the
+            # unrolled programs added to its base; each machine takes
+            # the decoded stage of the first machine with its key
+            first: Dict[tuple, int] = {}
+            owner = [first.setdefault(
+                (lat[i].tobytes(), mpg[i],
+                 _unrolled_bytes(base) if pr.unroll else b""), i)
+                for i, base in enumerate(bases)]
+            served = Counter(owner)
+            distinct = list(first.values())
+            sp.set_metadata(machines=served[distinct[0]])
+            plan.fill(bases[distinct[0]], lat[distinct[0]], mpg[distinct[0]])
+        for i in distinct[1:]:
+            with span("repro.sim.finish", machines=served[i]):
+                plan.fill(bases[i], lat[i], mpg[i])
+        return [bases[i] for i in owner]
+
+
+def _unrolled_bytes(out: _DecodedStage) -> bytes:
+    """What a machine's unrolled programs put into its decoded stage,
+    as bytes (floats pickle exactly)."""
+    return pickle.dumps((out.items, out.busy, out.unit_used, out.events,
+                         out.ev_present, out.n_static))
 
 
 def run_stage(sim: Any, sp: Any) -> Optional[Tuple[float, Dict[str, float],

@@ -163,92 +163,123 @@ class _Prep:
         return c
 
 
-def _finish_decode(out: _DecodedStage, pr: _Prep, unit: np.ndarray,
-                   lat: np.ndarray, bitems: Dict[int, tuple],
-                   ev_tot: List[float], ev_cnt: List[int]) -> None:
-    """Back half of a stage decode, shared by the numpy and JAX paths.
+class _StagePlan:
+    """Machine-independent half of a stage's back half.
 
-    From the per-instruction latencies and resolved boundary items,
-    collapse each program into unit runs + boundary replay items and
-    accumulate the static busy / event / instruction totals into
-    ``out``.  Everything here is plain numpy over ``pr``'s columns, so
-    both engines produce byte-identical replay plans given identical
-    ``lat`` / ``bitems``.
+    Everything the replay plan takes from the stage's columns and
+    boundary items alone: the boundary mask, the unit runs (first and
+    last row, unit), the per-unit instruction counts, the event counts,
+    and per core a template of its replay items with the boundary and
+    ``_END`` items in place and each block slot held as a run range.
+    :meth:`fill` completes it with one machine's latencies, so a fleet
+    builds the plan once per stage and fills it once per distinct
+    latency row.  Shared by the numpy and JAX engines, so both build
+    byte-identical replay plans from identical latencies and items.
     """
-    kind, pid, offs = pr.kind, pr.pid, pr.offs
-    cids, n = pr.cids, pr.n
-    bmask = kind >= _K_SEND
-    bound_pos = np.flatnonzero(bmask)
-    for p in np.flatnonzero(kind == _K_HALT).tolist():
-        bitems[p] = (_K_HALT,)
 
-    # ---- unit runs --------------------------------------------------
-    nb = ~bmask
-    run_start = nb.copy()
-    run_start[1:] &= (unit[1:] != unit[:-1]) | bmask[:-1]
-    run_start[offs[:-1]] = nb[offs[:-1]]         # break at core boundary
-    rs = np.flatnonzero(run_start)
-    mstep = np.maximum(1.0, lat)
-    mstep[bmask] = 0.0
-    if len(rs):
-        marks = np.flatnonzero(run_start | bmask)
-        mext = np.append(marks, n)
-        ends = mext[np.searchsorted(marks, rs, side="right")] - 1
-        run_A = np.add.reduceat(mstep, rs) - mstep[ends]
-        runs = list(zip(unit[rs].tolist(), run_A.tolist(),
-                        lat[ends].tolist()))
-    else:
-        runs = []
+    __slots__ = ("unit", "bmask", "rs", "ends", "unit_rs", "cnt",
+                 "n_static", "ev_cnt", "cores")
 
-    # ---- static stage totals ----------------------------------------
-    lat_nb = np.where(bmask, 0.0, lat)
-    busy = np.bincount(unit, weights=lat_nb, minlength=4)
-    cnt = np.bincount(unit[nb], minlength=4)
-    for u in range(4):
-        out.busy[u] += float(busy[u])
-        out.unit_used[u] = out.unit_used[u] or bool(cnt[u])
-    for k in range(4):
-        out.events[k] += ev_tot[k]
-        out.ev_present[k] = out.ev_present[k] or ev_cnt[k] > 0
-    out.n_static += int(nb.sum())
+    def __init__(self, pr: _Prep, unit: np.ndarray,
+                 bitems: Dict[int, tuple], ev_cnt: List[int]) -> None:
+        kind, pid, offs = pr.kind, pr.pid, pr.offs
+        n = pr.n
+        bmask = kind >= _K_SEND
+        bound_pos = np.flatnonzero(bmask)
+        for p in np.flatnonzero(kind == _K_HALT).tolist():
+            bitems[p] = (_K_HALT,)
 
-    # ---- assemble per-core replay items -----------------------------
-    # all run-index lookups batched: for each boundary, the block
-    # before it spans runs [kp, kb); per-program tails span [kt, kh)
-    nb_b = len(bound_pos)
-    prange = np.arange(len(pr.packs))
-    b_by_pid = pid[bound_pos]
-    b_first = np.searchsorted(b_by_pid, prange, side="left")
-    b_last = np.searchsorted(b_by_pid, prange, side="right")
-    prev_pos = np.empty(nb_b, dtype=np.int64)
-    if nb_b:
-        prev_pos[0] = offs[b_by_pid[0]]
-        same = b_by_pid[1:] == b_by_pid[:-1]
-        prev_pos[1:] = np.where(same, bound_pos[:-1] + 1,
-                                offs[b_by_pid[1:]])
-    kb = np.searchsorted(rs, bound_pos).tolist()
-    kp = np.searchsorted(rs, prev_pos).tolist()
-    tail_pos = np.where(b_last > b_first,
-                        bound_pos[np.maximum(b_last - 1, 0)] + 1
-                        if nb_b else offs[:-1],
-                        offs[:-1][prange])
-    kt = np.searchsorted(rs, tail_pos).tolist()
-    kh = np.searchsorted(rs, offs[1:]).tolist()
-    bp_list = bound_pos.tolist()
-    for p, cid in enumerate(cids):
-        items: List[tuple] = []
-        hi = int(offs[p + 1])
-        b0, b1 = int(b_first[p]), int(b_last[p])
-        for i in range(b0, b1):
-            if kb[i] > kp[i]:
-                items.append((_BLOCK, runs[kp[i]:kb[i]]))
-            items.append(bitems[bp_list[i]])
-        if kh[p] > kt[p]:
-            items.append((_BLOCK, runs[kt[p]:kh[p]]))
-        if not (b1 > b0 and bitems[bp_list[b1 - 1]][0] == _K_HALT
-                and bp_list[b1 - 1] == hi - 1):
-            items.append((_END,))
-        out.items[cid] = items
+        # ---- unit runs --------------------------------------------------
+        nb = ~bmask
+        run_start = nb.copy()
+        run_start[1:] &= (unit[1:] != unit[:-1]) | bmask[:-1]
+        run_start[offs[:-1]] = nb[offs[:-1]]     # break at core boundary
+        rs = np.flatnonzero(run_start)
+        self.unit, self.bmask, self.rs, self.ev_cnt = unit, bmask, rs, ev_cnt
+        self.ends = None
+        if len(rs):
+            marks = np.flatnonzero(run_start | bmask)
+            mext = np.append(marks, n)
+            self.ends = mext[np.searchsorted(marks, rs, side="right")] - 1
+        self.unit_rs = unit[rs].tolist()
+        self.cnt = np.bincount(unit[nb], minlength=4)
+        self.n_static = int(nb.sum())
+
+        # ---- per-core replay item templates -----------------------------
+        # all run-index lookups batched: for each boundary, the block
+        # before it spans runs [kp, kb); per-program tails span [kt, kh)
+        nb_b = len(bound_pos)
+        prange = np.arange(len(pr.packs))
+        b_by_pid = pid[bound_pos]
+        b_first = np.searchsorted(b_by_pid, prange, side="left")
+        b_last = np.searchsorted(b_by_pid, prange, side="right")
+        prev_pos = np.empty(nb_b, dtype=np.int64)
+        if nb_b:
+            prev_pos[0] = offs[b_by_pid[0]]
+            same = b_by_pid[1:] == b_by_pid[:-1]
+            prev_pos[1:] = np.where(same, bound_pos[:-1] + 1,
+                                    offs[b_by_pid[1:]])
+        kb = np.searchsorted(rs, bound_pos).tolist()
+        kp = np.searchsorted(rs, prev_pos).tolist()
+        tail_pos = np.where(b_last > b_first,
+                            bound_pos[np.maximum(b_last - 1, 0)] + 1
+                            if nb_b else offs[:-1],
+                            offs[:-1][prange])
+        kt = np.searchsorted(rs, tail_pos).tolist()
+        kh = np.searchsorted(rs, offs[1:]).tolist()
+        bp_list = bound_pos.tolist()
+        # per core: (core id, items with None in each block slot,
+        # [(slot, first run, end run)])
+        self.cores: List[Tuple[int, List[Any], List[Tuple[int, ...]]]] = []
+        for p, cid in enumerate(pr.cids):
+            items: List[Any] = []
+            slots: List[Tuple[int, int, int]] = []
+            hi = int(offs[p + 1])
+            b0, b1 = int(b_first[p]), int(b_last[p])
+            for i in range(b0, b1):
+                if kb[i] > kp[i]:
+                    slots.append((len(items), kp[i], kb[i]))
+                    items.append(None)
+                items.append(bitems[bp_list[i]])
+            if kh[p] > kt[p]:
+                slots.append((len(items), kt[p], kh[p]))
+                items.append(None)
+            if not (b1 > b0 and bitems[bp_list[b1 - 1]][0] == _K_HALT
+                    and bp_list[b1 - 1] == hi - 1):
+                items.append((_END,))
+            self.cores.append((cid, items, slots))
+
+    def fill(self, out: _DecodedStage, lat: np.ndarray,
+             ev_tot: List[float]) -> None:
+        """Collapse one machine's per-instruction latencies into unit
+        runs, fill the item templates with them, and accumulate the
+        static busy / event / instruction totals into ``out``."""
+        bmask, rs, ends = self.bmask, self.rs, self.ends
+        mstep = np.maximum(1.0, lat)
+        mstep[bmask] = 0.0
+        if len(rs):
+            run_A = np.add.reduceat(mstep, rs) - mstep[ends]
+            runs = list(zip(self.unit_rs, run_A.tolist(),
+                            lat[ends].tolist()))
+        else:
+            runs = []
+
+        # ---- static stage totals ----------------------------------------
+        lat_nb = np.where(bmask, 0.0, lat)
+        busy = np.bincount(self.unit, weights=lat_nb, minlength=4)
+        for u in range(4):
+            out.busy[u] += float(busy[u])
+            out.unit_used[u] = out.unit_used[u] or bool(self.cnt[u])
+        for k in range(4):
+            out.events[k] += ev_tot[k]
+            out.ev_present[k] = out.ev_present[k] or self.ev_cnt[k] > 0
+        out.n_static += self.n_static
+
+        for cid, template, slots in self.cores:
+            items = template.copy()
+            for j, a, b in slots:
+                items[j] = (_BLOCK, runs[a:b])
+            out.items[cid] = items
 
 
 class StageDecoder:
@@ -812,7 +843,7 @@ class StageDecoder:
             for p, b in zip(sync.tolist(), barrier.tolist()):
                 bitems[p] = (_K_SYNC, b)
 
-        _finish_decode(out, pr, unit, lat, bitems, ev_tot, ev_cnt)
+        _StagePlan(pr, unit, bitems, ev_cnt).fill(out, lat, ev_tot)
         return out
 
 

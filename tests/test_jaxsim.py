@@ -12,6 +12,7 @@ routing/caching behaviour, and the ``func:pallas`` oracle backend.
 """
 
 import dataclasses
+import itertools
 import os
 
 import numpy as np
@@ -321,6 +322,133 @@ def test_fleet_stage_decoder_matches_per_machine():
         out_s = Simulator(chip, ISA, engine="scalar")._run_stage(sp,
                                                                  None)
         assert_identical(out_s, out_f)
+
+
+# a timing_space() grid on two structures: the default chip (tiny CNN)
+# and a 16-core chip of single-macro groups that spreads a tiny looped
+# LM over 16 stages with boundary items (as tests/test_arch_lowering.py)
+_TINY_ARCH = dict(arch="ouro-2.6b", seq=8, layers=2, loop_steps=2,
+                  d_model=64, n_heads=4, n_kv_heads=4, head_dim=16,
+                  d_ff=176)
+_FLEET_MODELS = [
+    pytest.param("tiny_cnn", {}, 2, {}, id="tiny_cnn"),
+    pytest.param("arch", _TINY_ARCH, 1,
+                 dict(n_cores=16, n_macro_groups=2, macros_per_group=1),
+                 id="arch"),
+]
+
+
+def _grid_fleet(model, kw, batch, structure):
+    """The points of a 2 x 2 x 2 timing_space() grid (scalar ALU x
+    weight-load rate x router latency) and the program they share."""
+    pts = [dataclasses.replace(p, **structure) for p in timing_space(
+        scalar_alu=(1, 2), vector_alu=(2,), wl_rate=(1, 4),
+        router=(1, 2)).points()]
+    chips = [p.chip() for p in pts]
+    cm = flow.compile(model, canonical_chip(chips[0]), flow.CompileOptions(
+        strategy="dp", params=CostParams(batch=batch), fidelity="simulate",
+        workload_kw=kw or None)).ensure_model()
+    return pts, chips, cm
+
+
+def _plan(ds):
+    return (ds.items, ds.n_prog, ds.busy, ds.unit_used, ds.events,
+            ds.ev_present, ds.n_static)
+
+
+@pytest.mark.parametrize("model,kw,batch,structure", _FLEET_MODELS)
+def test_fleet_shares_a_decoded_stage_only_across_router_latency(
+        model, kw, batch, structure):
+    """Router latency enters replay only, so machines that differ in it
+    alone get one decoded stage object; machines whose scalar ALU or
+    weight-load latencies differ get their own.  Every machine's plan
+    equals its own single-machine decode's."""
+    pts, chips, cm = _grid_fleet(model, kw, batch, structure)
+    machines = [machine_for(c) for c in chips]
+    dec = jaxsim.FleetStageDecoder(cm.isa, machines)
+    for sp in cm.stages:
+        outs = dec.decode_stage(sp.programs)
+        for i, j in itertools.combinations(range(len(pts)), 2):
+            router_only = (dataclasses.replace(pts[i], router_latency=0)
+                           == dataclasses.replace(pts[j], router_latency=0))
+            assert (outs[i] is outs[j]) == router_only, (i, j)
+        for m, ds in zip(machines, outs):
+            one = jaxsim.JaxStageDecoder(cm.isa, m).decode_stage(
+                sp.programs)
+            assert _plan(ds) == _plan(one)
+
+
+@pytest.mark.parametrize("model,kw,batch,structure", _FLEET_MODELS)
+def test_fleet_sharing_keeps_each_machines_statistics(
+        model, kw, batch, structure):
+    """With decoded stages shared, every machine's cycles, energy, unit
+    busy and instruction count still equal its own
+    ``Simulator(engine="jax")`` run."""
+    pts, chips, cm = _grid_fleet(model, kw, batch, structure)
+    fe = FleetEvaluator(cm.cg, params=CostParams(batch=batch))
+    payloads = fe.evaluate([(c, "dp") for c in chips])
+    dec = jaxsim.FleetStageDecoder(cm.isa, [machine_for(c)
+                                            for c in chips])
+    replays = [dec.decode_stage(sp.programs) for sp in cm.stages]
+    for i, (chip, pl) in enumerate(zip(chips, payloads)):
+        sim = Simulator(chip, cm.isa, engine="jax")
+        rep = sim.run_model(cm)
+        assert pl["cycles"] == rep.cycles
+        assert pl["energy"] == dict(rep.energy())
+        for sp, outs in zip(cm.stages, replays):
+            assert_identical(jaxsim.run_stage(sim, sp),
+                             vectorsim.replay_stage(sim, sp, outs[i]))
+
+
+def _with_branch_penalty(chip, v):
+    return dataclasses.replace(chip, core=dataclasses.replace(
+        chip.core, scalar=dataclasses.replace(chip.core.scalar,
+                                              branch_penalty=v)))
+
+
+def _with_macros_per_group(chip, v):
+    return dataclasses.replace(chip, core=dataclasses.replace(
+        chip.core, cim=dataclasses.replace(chip.core.cim,
+                                           macros_per_group=v)))
+
+
+_VEC_AND_BRANCHY = {
+    0: [I("V_SETVL", len=40), I("V_ADD", dst=1, a=2, b=3), I("HALT",)],
+    1: [I("S_ADDI", dst=1, a=0, imm=3), I("S_ADDI", dst=2, a=0, imm=0),
+        I("S_ADDI", dst=1, a=1, imm=-1), I("BNE", a=1, b=2, off=-1),
+        I("HALT",)]}
+_MVM = {0: [I("CIM_CFG", sreg=SREG["MG_NLEN"], imm=16),
+            I("S_ADDI", dst=1, a=0, imm=0),
+            I("CIM_LOAD", mg=0, src=1, rows=64),
+            I("CIM_LOAD", mg=2, src=1, rows=32),
+            I("CIM_CFG", sreg=SREG["MG_MASK_LO"], imm=0b101),
+            I("CIM_MVM", dst=1, src=1, rep=7, acc=0), I("HALT",)]}
+
+
+@pytest.mark.parametrize("change,bodies", [
+    # enters only the decode-time unroll of a program with control flow
+    pytest.param(_with_branch_penalty, _VEC_AND_BRANCHY, id="unroll"),
+    # enters only the macro-pass ledger, never a latency
+    pytest.param(_with_macros_per_group, _MVM, id="macros_per_group"),
+])
+def test_fleet_keys_every_machine_input_of_a_finish(change, bodies):
+    """Two machines whose latency rows are equal but that differ in
+    another input of their decoded stage keep one each, while a
+    router-only pair still shares one."""
+    base = CHIP
+    chips = [base, dataclasses.replace(
+        base, noc=dataclasses.replace(base.noc, router_latency=1)),
+        change(base, 5)]
+    programs = {c: prog(c, *body) for c, body in bodies.items()}
+    machines = [machine_for(c) for c in chips]
+    dec = jaxsim.FleetStageDecoder(ISA, machines)
+    outs = dec.decode_stage(programs)
+    assert outs[0] is outs[1] and outs[2] is not outs[0]
+    for m, ds in zip(machines, outs):
+        one = jaxsim.JaxStageDecoder(ISA, m).decode_stage(programs)
+        assert _plan(ds) == _plan(one)
+    # the batched core's items, built from the latency row alone, agree
+    assert outs[0].items[0] == outs[2].items[0]
 
 
 def test_device_call_downloads_integers_only():

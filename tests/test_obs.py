@@ -141,3 +141,34 @@ def test_oracle_span_counts_its_contractions_by_path(tmp_path):
               if n == "repro.ref.oracle"]
     n_mvm = sum(g.anchor is not None for g in art.cg)
     assert oracle == [{"f64": 2 * n_mvm}]
+
+
+def test_fleet_finish_spans_count_the_machines_they_serve(tmp_path):
+    """A fleet opens one ``repro.sim.finish`` span per distinct decoded
+    stage, its ``machines`` counter the machines that stage serves:
+    per stage the counters sum to the fleet size.  Router latency
+    enters replay only, so a 2 x 2 grid of scalar ALU x router latency
+    finishes twice per stage."""
+    from repro.core.jaxsim import FleetStageDecoder
+    from repro.core.machine import machine_for
+    from repro.explore import timing_space
+
+    chips = [p.chip() for p in timing_space(
+        scalar_alu=(1, 2), vector_alu=(1,), wl_rate=(1,),
+        router=(1, 2)).points()]
+    cm = flow.compile("tiny_cnn", chips[0], flow.CompileOptions(
+        strategy="dp", params=CostParams(batch=2),
+        fidelity="simulate")).ensure_model()
+    dec = FleetStageDecoder(cm.isa, [machine_for(c) for c in chips])
+    stages = cm.stages + cm.stages
+
+    def body():
+        for sp in stages:
+            dec.decode_stage(sp.programs)
+    per_stage = []
+    for _, name, _, _, stats in _trace(tmp_path, body):
+        if name == "repro.sim.device_call":
+            per_stage.append([])
+        elif name == "repro.sim.finish":
+            per_stage[-1].append(stats["machines"])
+    assert per_stage == [[2, 2]] * len(stages)
